@@ -12,12 +12,10 @@ without overflow on the read-heavy mix).
 
 from __future__ import annotations
 
-import os
-import subprocess
 import sys
 import textwrap
 
-from benchmarks.common import print_table, save_results
+from benchmarks.common import print_table, run_cpu_child, save_results
 
 SCRIPT = textwrap.dedent("""
     import os
@@ -27,6 +25,7 @@ SCRIPT = textwrap.dedent("""
     from repro import atomics
     from repro.core import distributed as dsb
     from repro.core import engine
+    from repro.launch.mesh import make_mesh
 
     n, k = 1 << {log_n}, 4
     p_local = {p_local}
@@ -57,7 +56,7 @@ SCRIPT = textwrap.dedent("""
     rows = []
     for strategy in strategies:
         for shards in shard_counts:
-            mesh = jax.make_mesh((shards, 8 // shards), ("shard", "rest"))
+            mesh = make_mesh((shards, 8 // shards), ("shard", "rest"))
             variants = [("baseline", dict())]
             if shards > 1:
                 variants.append(
@@ -108,14 +107,8 @@ def main(quick: bool = False):
         else ["seqlock", "indirect", "cached_wf", "cached_me"],
         shards=(1, 4) if quick else (1, 2, 4, 8),
         reps=5 if quick else 10)
-    env = dict(os.environ, PYTHONPATH=os.path.join(
-        os.path.dirname(__file__), "..", "src"))
-    r = subprocess.run([sys.executable, "-c", script], env=env,
-                       capture_output=True, text=True, timeout=3000)
-    line = [l for l in r.stdout.splitlines() if l.startswith("JSON:")]
-    assert line, r.stdout + r.stderr[-2000:]
-    import json
-    rows = json.loads(line[0][5:])
+    rows = [dict(row, backend="cpu")
+            for row in run_cpu_child(script, timeout=3000)]
     print_table("Distributed big atomics v2 (8 placeholder devices)", rows,
                 ["strategy", "variant", "shards", "mix", "contention",
                  "p_global", "mops_s", "overflow", "coll_bytes_dev"])

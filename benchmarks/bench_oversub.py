@@ -14,14 +14,11 @@ replay) — the number committed in BENCH_7.json.
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
 import textwrap
 import time
 
-from benchmarks.common import print_table, save_results
+from benchmarks.common import print_table, run_cpu_child, save_results
 
 # Fixed baseline shapes (independent of --quick, see baseline.py).
 N, K, WIDTH, SLOTS = 1 << 10, 4, 256, 2
@@ -70,6 +67,7 @@ RECOVERY_SCRIPT = textwrap.dedent("""
     import jax, numpy as np
     from repro import atomics
     from repro.core import distributed as dsb
+    from repro.launch.mesh import make_mesh
     from repro.runtime import (DistTarget, Executor, Fault, FaultInjector,
                                SyntheticStream)
 
@@ -79,7 +77,7 @@ RECOVERY_SCRIPT = textwrap.dedent("""
         s = 1
         while s * 2 <= n_surviving and n % (s * 2) == 0:
             s *= 2
-        mesh = jax.make_mesh((s, 8 // s), ("shard", "rest"))
+        mesh = make_mesh((s, 8 // s), ("shard", "rest"))
         return mesh, dsb.DistSpec(
             atomics.AtomicSpec(n, k, strategy, p_max=64), "shard", s,
             32 // s)
@@ -106,13 +104,7 @@ RECOVERY_SCRIPT = textwrap.dedent("""
 def run_recovery_cell() -> dict:
     """Injected mid-round shard loss on the 8-device fixture: measured
     recovery latency (restore + reshard + replay + re-checkpoint)."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(
-        os.path.dirname(__file__), "..", "src"))
-    r = subprocess.run([sys.executable, "-c", RECOVERY_SCRIPT], env=env,
-                       capture_output=True, text=True, timeout=900)
-    line = [l for l in r.stdout.splitlines() if l.startswith("JSON:")]
-    assert line, r.stdout + r.stderr[-2000:]
-    return json.loads(line[0][5:])
+    return dict(run_cpu_child(RECOVERY_SCRIPT, timeout=900), backend="cpu")
 
 
 def main(quick: bool = False):

@@ -30,15 +30,11 @@ single device only.
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import textwrap
 
 import numpy as np
 
-from benchmarks.common import print_table, save_results, time_op
+from benchmarks.common import print_table, run_cpu_child, save_results, time_op
 from repro import atomics
 from repro.sync.queue import BackoffPolicy
 
@@ -115,6 +111,7 @@ _DIST_SCRIPT = textwrap.dedent("""
     import jax, numpy as np
     from repro import atomics
     from repro.core import distributed as dsb
+    from repro.launch.mesh import make_mesh
 
     n, k, w = {n}, 2, {w}
     t = {t}
@@ -122,7 +119,7 @@ _DIST_SCRIPT = textwrap.dedent("""
     rows = []
     for strategy in strategies:
         for shards in {shards}:
-            mesh = jax.make_mesh((shards, 8 // shards), ("shard", "rest"))
+            mesh = make_mesh((shards, 8 // shards), ("shard", "rest"))
             dspec = dsb.DistSpec(atomics.AtomicSpec(n, k, strategy,
                                                     p_max=1024),
                                  "shard", shards, 8)
@@ -185,13 +182,8 @@ def main(quick: bool = False, tiny: bool = False):
             n=1 << 8, w=2, t=16 if quick else 64,
             strategies=["cached_me"] if quick else ["seqlock", "cached_me"],
             shards=(1, 4) if quick else (1, 2, 4, 8))
-        env = dict(os.environ, PYTHONPATH=os.path.join(
-            os.path.dirname(__file__), "..", "src"))
-        r = subprocess.run([sys.executable, "-c", script], env=env,
-                           capture_output=True, text=True, timeout=3000)
-        line = [l for l in r.stdout.splitlines() if l.startswith("JSON:")]
-        assert line, r.stdout + r.stderr[-2000:]
-        dist_rows = json.loads(line[0][5:])
+        dist_rows = [dict(row, backend="cpu")
+                     for row in run_cpu_child(script, timeout=3000)]
         print_table("Cross-shard MCAS (8 placeholder devices)", dist_rows,
                     ["strategy", "shards", "t", "w", "ktxn_s",
                      "commit_rate", "rounds", "coll_bytes_dev_round"])

@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import json
 import os
+import subprocess
+import sys
 import time
 
 import jax
@@ -24,6 +26,23 @@ def save_results(name: str, payload) -> str:
     with open(path, "w") as f:
         json.dump(payload, f, indent=1, default=float)
     return path
+
+
+def run_cpu_child(script: str, *, timeout: int):
+    """Run `script` in a child Python on the CPU backend and return the
+    JSON it prints on its `JSON:` line.
+
+    Children simulate several devices on the host (`XLA_FLAGS` in the
+    script).  They must not reach for the accelerator, which the parent
+    process holds once it has touched JAX, so their environment pins
+    `JAX_PLATFORMS=cpu`; callers label what comes back `backend: cpu`."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    r = subprocess.run([sys.executable, "-c", script], env=env,
+                       capture_output=True, text=True, timeout=timeout)
+    line = [ln for ln in r.stdout.splitlines() if ln.startswith("JSON:")]
+    assert line, r.stdout + r.stderr[-2000:]
+    return json.loads(line[0][5:])
 
 
 def time_op(fn, *args, reps: int = 5, warmup: int = 2):

@@ -28,9 +28,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmarks import (bench_atomics, bench_cachehash, bench_distributed,
                         bench_faults, bench_llsc, bench_memory, bench_obs,
                         bench_oversub, bench_torn, bench_txn)
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--skip", default="", help="comma-list to skip")

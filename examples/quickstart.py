@@ -14,6 +14,9 @@ import jax.numpy as jnp
 from repro.core import semantics as sem
 from repro.core.bigatomic import BigAtomicTable, begin_update, read_protocol
 from repro.core.cachehash import CacheHash
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 # --- a table of 1024 cells x 4 words, Cached-Memory-Efficient --------------
 table = BigAtomicTable(n=1024, k=4, strategy="cached_me", p_max=256)

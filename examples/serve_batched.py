@@ -17,11 +17,13 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import init_params
 from repro.serving import Request, ServingEngine
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-7b")
     ap.add_argument("--strategy", default="cached_me",

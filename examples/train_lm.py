@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.shapes import Shape
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.train import train
 
 
@@ -34,6 +35,7 @@ def model_tiny():
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--steps", type=int, default=None)

@@ -52,7 +52,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import cachehash as ch
 from repro.core import engine
@@ -403,8 +402,8 @@ def _build_table_apply(mesh: Mesh, dspec: DistSpec):
         return _restack(st), nctx, value, success, overflow
 
     spec = P(axis)
-    mapped = shard_map(local_fn, mesh=mesh, in_specs=(spec,) * 6,
-                       out_specs=(spec,) * 5, check_rep=False)
+    mapped = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec,) * 6,
+                           out_specs=(spec,) * 5, check_vma=False)
     return jax.jit(mapped)
 
 
@@ -520,8 +519,8 @@ def _build_table_apply_2level(mesh: Mesh, dspec: DistSpec):
         return _restack(st), nctx, value, success, overflow
 
     spec = P((node_axis, axis))
-    mapped = shard_map(local_fn, mesh=mesh, in_specs=(spec,) * 6,
-                       out_specs=(spec,) * 5, check_rep=False)
+    mapped = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec,) * 6,
+                           out_specs=(spec,) * 5, check_vma=False)
     return jax.jit(mapped)
 
 
@@ -749,8 +748,8 @@ def _build_mcas_apply(mesh: Mesh, dspec: DistSpec, t_local: int, w: int):
                 l_wit.reshape(t_local, w, k))
 
     spec = P(axis)
-    mapped = shard_map(local_fn, mesh=mesh, in_specs=(spec,) * 5,
-                       out_specs=(spec,) * 4, check_rep=False)
+    mapped = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec,) * 5,
+                           out_specs=(spec,) * 4, check_vma=False)
     return jax.jit(mapped)
 
 
@@ -899,8 +898,8 @@ def _build_hash_apply(mesh: Mesh, dspec: DistSpec):
         return _restack(st), found, val, walk_over, overflow
 
     spec = P(axis)
-    mapped = shard_map(local_fn, mesh=mesh, in_specs=(spec,) * 4,
-                       out_specs=(spec,) * 5, check_rep=False)
+    mapped = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec,) * 4,
+                           out_specs=(spec,) * 5, check_vma=False)
     return jax.jit(mapped)
 
 

@@ -168,7 +168,7 @@ def make_ops(kind, slot, expected=None, desired=None, *, k: int) -> OpBatch:
         raise ValueError(f"slot shape {slot.shape} != ({p},)")
     try:
         kind_np = np.asarray(kind)          # concrete only; tracers skip
-    except Exception:
+    except jax.errors.TracerArrayConversionError:
         kind_np = None
     if kind_np is not None:
         bad = np.setdiff1d(kind_np, np.arange(DELETE + 1))
@@ -574,7 +574,7 @@ def check_kinds(kind, allowed, what: str) -> None:
     kinds are the caller's contract — the oracle would raise on them)."""
     try:
         kind_np = np.asarray(kind)
-    except Exception:
+    except jax.errors.TracerArrayConversionError:
         return
     bad = np.setdiff1d(kind_np, np.asarray(allowed))
     if bad.size:

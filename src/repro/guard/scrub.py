@@ -73,31 +73,46 @@ def digest_np(logical, versions) -> np.ndarray:
     return h
 
 
-def _digest_pallas(vals, ver, *, block: int = 8, interpret: bool = True):
+def _digest_pallas(vals, ver, *, interpret: bool):
+    """Blocked Pallas digest, one cell per lane.
+
+    Cells run along lanes so that the word-serial FNV chain is a vector
+    op over a block of cells.  For k < 128 the device already keeps
+    `vals` column-major, so `vals.T` is a bitcast; wider rows are
+    transposed block by block in the kernel.  (Taking `vals` as [n, k]
+    and `ver` as [n, 1] would re-lay both into copies padded to 128
+    lanes.)"""
     from jax.experimental import pallas as pl
     n, k = vals.shape
-
-    def kernel(vals_ref, ver_ref, out_ref):
-        h = jnp.full(ver_ref.shape, FNV_OFFSET, jnp.uint32)
-        for j in range(k):
-            h = (h ^ vals_ref[:, j:j + 1]) * FNV_PRIME
-        out_ref[...] = (h ^ ver_ref[...]) * FNV_PRIME
-
+    cols = k < 128
+    # Blocks of <= 2^18 words, whole 128-lane vregs, no wider than the
+    # (padded) table.
+    block = min(max(128, (2 ** 18 // k) // 128 * 128), 8192,
+                -(-n // 128) * 128)
     pad = (-n) % block
     if pad:
-        vals = jnp.concatenate(
-            [vals, jnp.zeros((pad, k), jnp.uint32)], axis=0)
-        ver = jnp.concatenate([ver, jnp.zeros((pad,), jnp.uint32)])
+        vals = jnp.pad(vals, ((0, pad), (0, 0)))
+        ver = jnp.pad(ver, (0, pad))
+
+    def kernel(vals_ref, ver_ref, out_ref):
+        v = vals_ref[...] if cols else vals_ref[...].T
+        h = jnp.full((1, block), FNV_OFFSET, jnp.uint32)
+        for j in range(k):
+            h = (h ^ v[j:j + 1, :]) * FNV_PRIME
+        out_ref[...] = (h ^ ver_ref[...]) * FNV_PRIME
+
+    row = pl.BlockSpec((1, block), lambda i: (0, i))
     out = pl.pallas_call(
         kernel,
         grid=((n + pad) // block,),
-        in_specs=[pl.BlockSpec((block, k), lambda i: (i, 0)),
-                  pl.BlockSpec((block, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n + pad, 1), jnp.uint32),
+        in_specs=[pl.BlockSpec((k, block), lambda i: (0, i)) if cols
+                  else pl.BlockSpec((block, k), lambda i: (i, 0)),
+                  row],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((1, n + pad), jnp.uint32),
         interpret=interpret,
-    )(vals, ver[:, None])
-    return out[:n, 0]
+    )(vals.T if cols else vals, ver.reshape(1, n + pad))
+    return out[0, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "mode", "interpret"))

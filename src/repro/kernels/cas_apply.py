@@ -31,7 +31,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_ANY = pltpu.TPUMemorySpace.ANY
+_ANY = pltpu.MemorySpace.ANY
 
 STORE = 1
 CAS = 2

@@ -13,17 +13,17 @@ round trip; this module is that path made real at the XLA/Pallas level:
               evaluates LOAD/STORE/CAS/LL/SC/VALIDATE in registers, and
               scatters data+version back — no sort, no scans, no rounds.
               On TPU this is a Pallas kernel (grid over lane tiles of
-              `block` lanes, scalar-prefetched slot routing as in
-              `cas_apply.py`, input/output aliasing, conditional write-back
-              DMA); off-TPU it is the equivalent O(p) gather/compute/scatter
-              XLA program.
+              `block` lanes, per-lane scalars in SMEM, input/output
+              aliasing, conditional write-back DMA); off-TPU it is the
+              equivalent O(p) gather/compute/scatter XLA program.
 
   slow path   Contended batches sort by (slot, lane) once, then ONE Pallas
               pass replays the sorted lanes sequentially per cell segment:
-              a cell row is DMA'd into VMEM at its segment start, all its
-              ops apply in registers, and the row is written back at the
-              segment end — each dirty cell makes exactly one HBM round
-              trip instead of L gather/scatter rounds.  Off-TPU the slow
+              the window holding a cell is DMA'd into VMEM at its segment
+              start, all its ops apply in registers, and the window is
+              written back at the segment end — each dirty cell makes
+              exactly one HBM round trip instead of L gather/scatter
+              rounds.  Off-TPU the slow
               path is `engine.linearize` itself (the pure-XLA reference).
 
   dispatch    `fast_path_ok` is one cheap duplicate-scatter check; a
@@ -62,10 +62,9 @@ from repro.core.engine import (
     STORE, VALIDATE,
 )
 
-_ANY = pltpu.TPUMemorySpace.ANY
+_ANY = pltpu.MemorySpace.ANY
 
-# Lane-tile width: 8 sublanes per grid step, so a block of k-word payloads
-# is one (8, k<=128) register tile (ops.pad_cells lane-aligns k on TPU).
+# Lanes per grid step: the fast tier keeps this many window DMAs in flight.
 DEFAULT_BLOCK = 8
 
 _MODES = ("auto", "pallas", "xla", "off")
@@ -210,86 +209,294 @@ def _fast_xla(n: int, data, version, ctx: LinkCtx, ops: OpBatch):
 
 
 # ---------------------------------------------------------------------------
+# The table as the kernels see it: the state's own buffers, re-viewed.
+# ---------------------------------------------------------------------------
+#
+# A Pallas kernel takes an HBM operand in its plain row-major (8, 128)-tiled
+# layout; XLA re-lays any other operand into a copy.  The device keeps
+# data[n, k] with k < 128 column-major, in (k, 128) tiles of 128 cells, and
+# handed over as [n, k] it would be copied and padded to 128 lanes -- 32x for
+# k=4, 128x for version[n, 1] -- which at a deployment's table size does not
+# fit the chip.  So the kernels see the same bytes under shapes whose
+# row-major layout they already have:
+#
+#   data     k < 128   data.T: [k, n]         cell s = column s
+#            k >= 128  [n * k / 128, 128]     cell s = rows [s*k/128, ...)
+#   version            [n / 128, 128]         cell s = row s // 128, lane s % 128
+#
+# Both are bitcasts when 128 divides n and k fits Mosaic's row tiling (k of
+# 1, 2, 4 or a multiple of 8 below 128; a multiple of 128 above).  Other
+# widths are padded to the next such width, a copy of the table.  A lane
+# moves the window of whole 128-lane rows that holds its cell -- k columns
+# or k/128 rows, the units Mosaic can DMA at any cell -- and picks its own
+# words out of it with a lane mask.
+
+LANES = 128
+
+# Per-lane scalars: one SMEM row of _NMETA int32 words per lane.
+_NMETA = 8
+_DPOS, _VROW, _LANE, _KIND, _LINK, _FLAGS = range(6)
+_LIVE, _SEG_START, _SEG_END = 1, 2, 4
+
+
+def _columns(k: int) -> bool:
+    """True where the data view is data.T (one cell per lane)."""
+    return k < LANES
+
+
+def _view_words(k: int) -> int:
+    """Words per cell in the view: k padded to Mosaic's row tiling."""
+    if not _columns(k):
+        return -(-k // LANES) * LANES
+    if k in (1, 2, 4):
+        return k
+    return 4 if k == 3 else -(-k // 8) * 8
+
+
+def _window_rows(k: int) -> int:
+    """Rows of the data window a lane moves: k columns, or k/128 rows."""
+    kw = _view_words(k)
+    return kw if _columns(k) else kw // LANES
+
+
+def _table_view(data, version):
+    n, k = data.shape
+    pad, kw = (-n) % LANES, _view_words(k)
+    if pad or kw != k:
+        data = jnp.pad(data, ((0, pad), (0, kw - k)))
+        version = jnp.pad(version, (0, pad))
+    vrows = version.reshape(-1, LANES)
+    if _columns(k):
+        return data.T, vrows
+    return data.reshape(-1, LANES), vrows
+
+
+def _table_unview(drows, vrows, n: int, k: int):
+    version = vrows.reshape(-1)[:n]
+    if _columns(k):
+        return drows.T[:n, :k], version
+    return drows.reshape(-1, _view_words(k))[:n, :k], version
+
+
+def _lane_rows(words):
+    """word[p, k] -> word[p * window_rows, 128]: each lane's operand laid out
+    like its data window, so a lane-mask select moves it into place."""
+    p, k = words.shape
+    kw = _view_words(k)
+    if kw != k:
+        words = jnp.pad(words, ((0, 0), (0, kw - k)))
+    if _columns(k):
+        return jnp.broadcast_to(words[:, :, None], (p, kw, LANES)).reshape(
+            p * kw, LANES)
+    return words.reshape(-1, LANES)
+
+
+def _pad_lanes(block: int, n: int, slot, kind, link_ver, expected, desired):
+    """Pad the batch to a whole number of lane tiles with dead lanes."""
+    pad = (-slot.shape[0]) % block
+    if not pad:
+        return slot, kind, link_ver, expected, desired
+    k = expected.shape[1]
+    return (jnp.concatenate([slot, jnp.full((pad,), n, jnp.int32)]),
+            jnp.concatenate([kind, jnp.full((pad,), IDLE, jnp.int32)]),
+            jnp.concatenate([link_ver, jnp.ones((pad,), jnp.uint32)]),
+            jnp.concatenate([expected, jnp.zeros((pad, k), expected.dtype)]),
+            jnp.concatenate([desired, jnp.zeros((pad, k), desired.dtype)]))
+
+
+def _lane_meta(n: int, k: int, slot, kind, link_ver, flags):
+    """int32[p, _NMETA]: each lane's window position, version row, lane,
+    kind, link version and flags.  Dead and out-of-contract lanes (slot
+    outside [0, n)) point at cell 0 and are flagged not live, so no DMA
+    index ever leaves the table."""
+    live = (slot >= 0) & (slot < n)
+    s = jnp.where(live, slot, 0)
+    lane = s % LANES
+    pos = s - lane if _columns(k) else s * _window_rows(k)
+    zero = jnp.zeros_like(s)
+    return jnp.stack([
+        pos, s // LANES, lane, kind,
+        lax.bitcast_convert_type(link_ver.astype(jnp.uint32), jnp.int32),
+        flags | jnp.where(live, _LIVE, 0), zero, zero], axis=1)
+
+
+def _lane_results(drows, vrows, wit, info, meta, n: int, k: int, p: int):
+    """Undo the views: (data', version', witness[p, k], ver_pt[p], flag[p]),
+    with flag the kernel's per-lane write or success bit."""
+    data, version = _table_unview(drows, vrows, n, k)
+    if _columns(k):
+        wit = wit.reshape(-1, _view_words(k), LANES)[:p]
+        wit = jnp.take_along_axis(wit, meta[:p, _LANE, None, None], axis=2)
+        wit = wit[:, :k, 0]
+    else:
+        wit = wit.reshape(-1, _view_words(k))[:p, :k]
+    return data, version, wit, info[:p, 0], info[:p, 1].astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# One op against its cell, on windows in VMEM (shared by both kernels).
+# ---------------------------------------------------------------------------
+
+def _window(data_ref, pos, k: int):
+    """The HBM window of whole 128-lane rows that holds a lane's cell."""
+    if _columns(k):
+        return data_ref.at[:, pl.ds(pl.multiple_of(pos, LANES), LANES)]
+    return data_ref.at[pl.ds(pos, _window_rows(k))]
+
+
+def _masks(k: int, lane):
+    """(data mask, version mask): the cell's words in its data window and
+    its word in its version row."""
+    dlane = lax.broadcasted_iota(jnp.int32, (_window_rows(k), LANES), 1)
+    vlane = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    dmask = dlane == lane if _columns(k) else dlane >= 0
+    return dmask, vlane == lane
+
+
+def _judge(cv, vv, exp, kd, lv, dmask, vmask):
+    """Evaluate one op.  cv/vv: the cell's data window and version row;
+    exp: the lane's comparand rows; kd, lv: its kind and link version
+    (scalars).  Returns (okw, succ, ver) as [1, 1] vectors: write success,
+    op success (int32) and the cell version the op saw (uint32)."""
+    eq = jnp.where(dmask, (cv == exp).astype(jnp.int32), 1)
+    match = jnp.min(jnp.min(eq, axis=1, keepdims=True), axis=0, keepdims=True)
+    # Mosaic reduces signed words only; the sum picks one word, so the
+    # bitcasts round-trip it exactly.
+    ver = lax.bitcast_convert_type(jnp.sum(
+        lax.bitcast_convert_type(jnp.where(vmask, vv, jnp.uint32(0)),
+                                 jnp.int32), axis=1, keepdims=True),
+        jnp.uint32)
+    link_ok = (ver == lv.astype(jnp.uint32)).astype(jnp.int32)
+
+    def is_(c):
+        return (kd == c).astype(jnp.int32)
+
+    okw = is_(STORE) + is_(CAS) * match + is_(SC) * link_ok
+    succ = (is_(LOAD) + is_(STORE) + is_(LL) + is_(CAS) * match
+            + (is_(SC) + is_(VALIDATE)) * link_ok)
+    return okw, succ, ver
+
+
+def _commit(cv, vv, des, okw, dmask, vmask):
+    """Window and version row after a write that succeeded iff okw."""
+    return (jnp.where(jnp.where(dmask, okw, 0) > 0, des, cv),
+            jnp.where(jnp.where(vmask, okw, 0) > 0, vv + jnp.uint32(2), vv))
+
+
+def _info(ver, flag):
+    """A lane's uint32[1, 128] result row: lane 0 the version at its point,
+    lane 1 its write/success bit."""
+    lane = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    return jnp.where(lane == 0, ver,
+                     jnp.where(lane == 1, flag.astype(jnp.uint32),
+                               jnp.uint32(0)))
+
+
+def _copy(src, dst, sem):
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
+
+def _round_call(kernel, k: int, block: int, meta, exp_rows, des_rows, drows,
+                vrows, scratch, interpret: bool):
+    pp, wr = meta.shape[0], _window_rows(k)
+    tile = pl.BlockSpec((block * wr, LANES), lambda i: (i, 0))
+    table = pl.BlockSpec(memory_space=_ANY)
+    return pl.pallas_call(
+        kernel,
+        grid=(pp // block,),
+        in_specs=[
+            pl.BlockSpec((block, _NMETA), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),              # lane scalars
+            tile,                                               # expected
+            tile,                                               # desired
+            table,                                              # data
+            table,                                              # version
+        ],
+        out_specs=[
+            table,                                              # data back
+            table,                                              # version back
+            tile,                                               # witness
+            pl.BlockSpec((block, LANES), lambda i: (i, 0)),     # lane info
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(drows.shape, drows.dtype),
+            jax.ShapeDtypeStruct(vrows.shape, vrows.dtype),
+            jax.ShapeDtypeStruct((pp * wr, LANES), drows.dtype),
+            jax.ShapeDtypeStruct((pp, LANES), jnp.uint32),
+        ],
+        scratch_shapes=scratch,
+        # the table is updated in place: data = input 3, version = input 4
+        input_output_aliases={3: 0, 4: 1},
+        interpret=interpret,
+    )(meta, exp_rows, des_rows, drows, vrows)
+
+
+# ---------------------------------------------------------------------------
 # The blocked fast-path Pallas kernel.
 # ---------------------------------------------------------------------------
 
-def _fast_kernel(n: int, block: int):
-    def kernel(slot_ref, kind_ref, linkver_ref, exp_ref, des_ref,
-               data_hbm, ver_hbm, out_data, out_ver, wit_ref, verpt_ref,
-               succ_ref, rows, vrows, sems, vsems, wsem):
-        b = pl.program_id(0)
+def _fast_kernel(k: int, block: int):
+    wr = _window_rows(k)
 
-        def _gathers(j):
-            # Dead (and out-of-contract) lanes clamp to row 0: the read is
-            # masked out below, and a DMA must never index outside the
-            # table (negative s would wrap in interpret mode and be a rogue
-            # DMA on silicon).
-            s = slot_ref[b * block + j]
-            sd = jnp.clip(s, 0, n - 1)
+    def kernel(meta_ref, exp_ref, des_ref, data_in, ver_in, out_data,
+               out_ver, wit_ref, info_ref, wins, vrows, win, vrow, sems,
+               vsems, sem):
+        del data_in, ver_in                 # aliased to out_data / out_ver
+
+        def m(j, f):
+            return meta_ref[j, f]
+
+        def gathers(j):
             return (
-                pltpu.make_async_copy(out_data.at[pl.ds(sd, 1)],
-                                      rows.at[pl.ds(j, 1)], sems.at[j]),
-                pltpu.make_async_copy(out_ver.at[pl.ds(sd, 1)],
+                pltpu.make_async_copy(_window(out_data, m(j, _DPOS), k),
+                                      wins.at[pl.ds(j * wr, wr)], sems.at[j]),
+                pltpu.make_async_copy(out_ver.at[pl.ds(m(j, _VROW), 1)],
                                       vrows.at[pl.ds(j, 1)], vsems.at[j]),
             )
 
-        # Phase 1 — overlapped gather: all of the tile's row DMAs in flight
-        # at once (fast-path contract: live lanes target distinct rows).
-        def start(j, _):
-            for cp in _gathers(j):
+        # Phase 1 -- overlapped gather: every lane's window in flight at
+        # once (dead lanes read cell 0's and ignore it).
+        for j in range(block):
+            for cp in gathers(j):
                 cp.start()
-            return 0
-
-        def wait(j, _):
-            for cp in _gathers(j):
+        for j in range(block):
+            for cp in gathers(j):
                 cp.wait()
-            return 0
 
-        lax.fori_loop(0, block, start, 0)
-        lax.fori_loop(0, block, wait, 0)
+        for j in range(block):
+            kd, live = m(j, _KIND), (m(j, _FLAGS) & _LIVE) != 0
+            r = slice(j * wr, (j + 1) * wr)
+            dmask, vmask = _masks(k, m(j, _LANE))
+            cv = wins[r, :]
+            okw, _, ver = _judge(cv, vrows[j:j + 1, :], exp_ref[r, :], kd,
+                                 m(j, _LINK), dmask, vmask)
+            on = live.astype(jnp.int32)
+            wit_ref[r, :] = cv * on.astype(cv.dtype)
+            info_ref[j:j + 1, :] = _info(ver * on.astype(ver.dtype), okw * on)
 
-        # Phase 2 — evaluate the whole tile in registers.
-        slots = jnp.stack([slot_ref[b * block + j] for j in range(block)])
-        live = (slots >= 0) & (slots < n)
-        cv = rows[...]                               # [block, k]
-        vr = vrows[...][:, 0]
-        kd = kind_ref[...][:, 0]
-        lv = linkver_ref[...][:, 0]
-        match = jnp.all(cv == exp_ref[...], axis=1)
-        okw = live & ((kd == STORE) | ((kd == CAS) & match)
-                      | ((kd == SC) & (lv == vr)))
-        wit_ref[...] = jnp.where(live[:, None], cv, jnp.zeros_like(cv))
-        verpt_ref[...] = jnp.where(live, vr, jnp.uint32(0))[:, None]
-        succ_ref[...] = okw.astype(jnp.int32)[:, None]
-        rows[...] = jnp.where(okw[:, None], des_ref[...], cv)
-        vrows[...] = (vr + jnp.uint32(2) * okw.astype(jnp.uint32))[:, None]
-
-        # Phase 3 — write-back only the lanes that actually wrote (their
-        # rows are distinct by the fast-path contract; serialized starts
-        # keep the common mostly-read case cheap).
-        def writeback(j, _):
-            s = slot_ref[b * block + j]
-
-            @pl.when(okw[j])
+            # Phase 2 -- each writing lane's write-back is a read-modify-
+            # write of its window: lanes hold distinct cells, but up to 128
+            # of them share a window, so each merges its own words into the
+            # window as it stands now.  The predicate is the lane's kind
+            # (SMEM); a CAS that failed writes its window back unchanged.
+            @pl.when(live & ((kd == STORE) | (kd == CAS) | (kd == SC)))
             def _():
-                cp = pltpu.make_async_copy(
-                    rows.at[pl.ds(j, 1)], out_data.at[pl.ds(s, 1)], wsem)
-                cp.start()
-                cp.wait()
-                cp = pltpu.make_async_copy(
-                    vrows.at[pl.ds(j, 1)], out_ver.at[pl.ds(s, 1)], wsem)
-                cp.start()
-                cp.wait()
-
-            return 0
-
-        lax.fori_loop(0, block, writeback, 0)
+                dwin = _window(out_data, m(j, _DPOS), k)
+                vwin = out_ver.at[pl.ds(m(j, _VROW), 1)]
+                _copy(dwin, win, sem)
+                _copy(vwin, vrow, sem)
+                win[...], vrow[...] = _commit(win[...], vrow[...],
+                                              des_ref[r, :], okw, dmask,
+                                              vmask)
+                _copy(win, dwin, sem)
+                _copy(vrow, vwin, sem)
 
     return kernel
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def fast_round_pallas(data, version, slot, kind, link_ver, expected, desired,
                       *, block: int = DEFAULT_BLOCK, interpret: bool = False):
     """One blocked fast-path pass.  data: word[n, k]; version: uint32[n];
@@ -299,61 +506,24 @@ def fast_round_pallas(data, version, slot, kind, link_ver, expected, desired,
 
     Returns (data', version', witness[p, k], ver_pt[p], okw[p])."""
     n, k = data.shape
-    p = slot.shape[0]
-    pad = (-p) % block
-    if pad:
-        slot = jnp.concatenate([slot, jnp.full((pad,), n, jnp.int32)])
-        kind = jnp.concatenate([kind, jnp.full((pad,), IDLE, jnp.int32)])
-        link_ver = jnp.concatenate([link_ver, jnp.ones((pad,), jnp.uint32)])
-        expected = jnp.concatenate(
-            [expected, jnp.zeros((pad, k), expected.dtype)])
-        desired = jnp.concatenate(
-            [desired, jnp.zeros((pad, k), desired.dtype)])
-    pp = p + pad
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(pp // block,),
-        in_specs=[
-            pl.BlockSpec((block, 1), lambda i, s: (i, 0)),     # kind
-            pl.BlockSpec((block, 1), lambda i, s: (i, 0)),     # link ver
-            pl.BlockSpec((block, k), lambda i, s: (i, 0)),     # expected
-            pl.BlockSpec((block, k), lambda i, s: (i, 0)),     # desired
-            pl.BlockSpec(memory_space=_ANY),                   # data
-            pl.BlockSpec(memory_space=_ANY),                   # version
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=_ANY),                   # data back
-            pl.BlockSpec(memory_space=_ANY),                   # version back
-            pl.BlockSpec((block, k), lambda i, s: (i, 0)),     # witness
-            pl.BlockSpec((block, 1), lambda i, s: (i, 0)),     # ver at point
-            pl.BlockSpec((block, 1), lambda i, s: (i, 0)),     # write ok
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block, k), data.dtype),
-            pltpu.VMEM((block, 1), jnp.uint32),
-            pltpu.SemaphoreType.DMA((block,)),
-            pltpu.SemaphoreType.DMA((block,)),
-            pltpu.SemaphoreType.DMA(()),
-        ],
-    )
-    new_data, new_ver, wit, verpt, okw = pl.pallas_call(
-        _fast_kernel(n, block),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n, k), data.dtype),
-            jax.ShapeDtypeStruct((n, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((pp, k), data.dtype),
-            jax.ShapeDtypeStruct((pp, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((pp, 1), jnp.int32),
-        ],
-        # alias the table through: 0 = slot prefetch, then 4 blocked inputs,
-        # so data=5, version=6
-        input_output_aliases={5: 0, 6: 1},
-        interpret=interpret,
-    )(slot, kind.reshape(pp, 1), link_ver.reshape(pp, 1).astype(jnp.uint32),
-      expected, desired, data, version.reshape(n, 1))
-    return (new_data, new_ver.reshape(n), wit[:p], verpt[:p, 0], okw[:p, 0])
+    p, wr = slot.shape[0], _window_rows(k)
+    slot, kind, link_ver, expected, desired = _pad_lanes(
+        block, n, slot, kind, link_ver, expected, desired)
+    meta = _lane_meta(n, k, slot, kind, link_ver, 0)
+    drows, vrows = _table_view(data, version)
+    scratch = [
+        pltpu.VMEM((block * wr, LANES), data.dtype),
+        pltpu.VMEM((block, LANES), jnp.uint32),
+        pltpu.VMEM((wr, LANES), data.dtype),
+        pltpu.VMEM((1, LANES), jnp.uint32),
+        pltpu.SemaphoreType.DMA((block,)),
+        pltpu.SemaphoreType.DMA((block,)),
+        pltpu.SemaphoreType.DMA(()),
+    ]
+    out = _round_call(_fast_kernel(k, block), k, block, meta,
+                      _lane_rows(expected), _lane_rows(desired), drows, vrows,
+                      scratch, interpret)
+    return _lane_results(*out, meta, n, k, p)
 
 
 def _fast_pallas(n: int, data, version, ctx: LinkCtx, ops: OpBatch, *,
@@ -371,72 +541,50 @@ def _fast_pallas(n: int, data, version, ctx: LinkCtx, ops: OpBatch, *,
 # The slow-path Pallas kernel: one sequential replay pass over sorted lanes.
 # ---------------------------------------------------------------------------
 
-def _slow_kernel(n: int, p: int, block: int):
-    def kernel(slot_ref, kind_ref, linkver_ref, exp_ref, des_ref,
-               data_hbm, ver_hbm, out_data, out_ver, valpt_ref, verpt_ref,
-               succ_ref, row, vrow, sem):
-        b = pl.program_id(0)
+def _slow_kernel(k: int, block: int):
+    wr = _window_rows(k)
+
+    def kernel(meta_ref, exp_ref, des_ref, data_in, ver_in, out_data,
+               out_ver, wit_ref, info_ref, win, vrow, sem):
+        del data_in, ver_in                 # aliased to out_data / out_ver
 
         def lane(j, _):
-            g = b * block + j
-            s = slot_ref[g]
-            # Same out-of-contract guard as the fast kernel: a negative slot
-            # must never become a DMA index.
-            live = (s >= 0) & (s < n)
-            prev = slot_ref[jnp.maximum(g - 1, 0)]
-            nxt = slot_ref[jnp.minimum(g + 1, p - 1)]
-            seg_start = (g == 0) | (s != prev)
-            seg_end = (g == p - 1) | (s != nxt)
+            def m(f):
+                return meta_ref[j, f]
 
-            @pl.when(live)
+            flags = m(_FLAGS)
+            dwin = _window(out_data, m(_DPOS), k)
+            vwin = out_ver.at[pl.ds(m(_VROW), 1)]
+            r = pl.ds(j * wr, wr)
+
+            @pl.when((flags & _LIVE) != 0)
             def _():
-                # Segment start: the cell row makes its ONE trip into VMEM.
-                @pl.when(seg_start)
+                # Segment start: the cell's window makes its ONE trip in.
+                @pl.when((flags & _SEG_START) != 0)
                 def _():
-                    cp = pltpu.make_async_copy(
-                        out_data.at[pl.ds(s, 1)], row, sem)
-                    cp.start()
-                    cp.wait()
-                    cp = pltpu.make_async_copy(
-                        out_ver.at[pl.ds(s, 1)], vrow, sem)
-                    cp.start()
-                    cp.wait()
+                    _copy(dwin, win, sem)
+                    _copy(vwin, vrow, sem)
 
-                cv = row[...]
-                vr = vrow[0, 0]
-                kd = kind_ref[j, 0]
-                match = jnp.all(cv == exp_ref[pl.ds(j, 1), :])
-                link_ok = linkver_ref[j, 0] == vr
-                okw = ((kd == STORE) | ((kd == CAS) & match)
-                       | ((kd == SC) & link_ok))
-                succ = ((kd == LOAD) | (kd == STORE) | (kd == LL)
-                        | ((kd == VALIDATE) & link_ok)
-                        | (((kd == CAS) | (kd == SC)) & okw))
-                valpt_ref[pl.ds(j, 1), :] = cv
-                verpt_ref[pl.ds(j, 1), :] = vrow[...]
-                succ_ref[pl.ds(j, 1), :] = succ.astype(jnp.int32)[None, None]
-                row[...] = jnp.where(okw, des_ref[pl.ds(j, 1), :], cv)
-                vrow[0, 0] = vr + jnp.uint32(2) * okw.astype(jnp.uint32)
+                dmask, vmask = _masks(k, m(_LANE))
+                cv, vv = win[...], vrow[...]
+                okw, succ, ver = _judge(cv, vv, exp_ref[r, :], m(_KIND),
+                                        m(_LINK), dmask, vmask)
+                wit_ref[r, :] = cv
+                info_ref[pl.ds(j, 1), :] = _info(ver, succ)
+                win[...], vrow[...] = _commit(cv, vv, des_ref[r, :], okw,
+                                              dmask, vmask)
 
-                # Segment end: write the (possibly dirty) row back.
-                @pl.when(seg_end)
+                # Segment end: write the (possibly dirty) window back.  A
+                # later segment on a cell of the same window reads it again.
+                @pl.when((flags & _SEG_END) != 0)
                 def _():
-                    cp = pltpu.make_async_copy(
-                        row, out_data.at[pl.ds(s, 1)], sem)
-                    cp.start()
-                    cp.wait()
-                    cp = pltpu.make_async_copy(
-                        vrow, out_ver.at[pl.ds(s, 1)], sem)
-                    cp.start()
-                    cp.wait()
+                    _copy(win, dwin, sem)
+                    _copy(vrow, vwin, sem)
 
-            @pl.when(~live)
+            @pl.when((flags & _LIVE) == 0)
             def _():
-                valpt_ref[pl.ds(j, 1), :] = jnp.zeros(
-                    (1, valpt_ref.shape[1]), valpt_ref.dtype)
-                verpt_ref[pl.ds(j, 1), :] = jnp.zeros(
-                    (1, 1), verpt_ref.dtype)
-                succ_ref[pl.ds(j, 1), :] = jnp.zeros((1, 1), jnp.int32)
+                wit_ref[r, :] = jnp.zeros((wr, LANES), wit_ref.dtype)
+                info_ref[pl.ds(j, 1), :] = jnp.zeros((1, LANES), jnp.uint32)
 
             return 0
 
@@ -452,11 +600,10 @@ def slow_round_pallas(data, version, s_slot, s_kind, s_link_ver, s_expected,
     """One fused sequential-replay pass over lanes SORTED by (slot, lane).
 
     Fuses the per-segment arbitration and all L combining rounds of
-    `engine.linearize._general` into one kernel: per-cell segment metadata
-    is derived from the scalar-prefetched sorted slots, a segment's cell row
-    is DMA'd in once, every op of the segment applies in registers (full
-    LOAD/STORE/CAS/LL/SC/VALIDATE semantics), and the row is written back
-    once — replacing the gather -> check -> scatter `while_loop` round
+    `engine.linearize._general` into one kernel: a segment's cell window is
+    DMA'd in once, every op of the segment applies in registers (full
+    LOAD/STORE/CAS/LL/SC/VALIDATE semantics), and the window is written back
+    once -- replacing the gather -> check -> scatter `while_loop` round
     trips.  The per-lane DMAs here are deliberately serialized: the replay
     is sequential by definition (lane j+1 may read what lane j wrote), so
     only the blocked op tiles pipeline across grid steps.
@@ -464,62 +611,26 @@ def slow_round_pallas(data, version, s_slot, s_kind, s_link_ver, s_expected,
     Returns (data', version', val_pt[p, k], ver_pt[p], success[p]) in the
     SORTED lane order."""
     n, k = data.shape
-    p = s_slot.shape[0]
-    pad = (-p) % block
-    if pad:
-        # Padding lanes are dead (slot n) and sort AFTER every live lane, so
-        # they never split a real segment.
-        s_slot = jnp.concatenate([s_slot, jnp.full((pad,), n, jnp.int32)])
-        s_kind = jnp.concatenate([s_kind, jnp.full((pad,), IDLE, jnp.int32)])
-        s_link_ver = jnp.concatenate(
-            [s_link_ver, jnp.ones((pad,), jnp.uint32)])
-        s_expected = jnp.concatenate(
-            [s_expected, jnp.zeros((pad, k), s_expected.dtype)])
-        s_desired = jnp.concatenate(
-            [s_desired, jnp.zeros((pad, k), s_desired.dtype)])
-    pp = p + pad
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(pp // block,),
-        in_specs=[
-            pl.BlockSpec((block, 1), lambda i, s: (i, 0)),     # kind
-            pl.BlockSpec((block, 1), lambda i, s: (i, 0)),     # link ver
-            pl.BlockSpec((block, k), lambda i, s: (i, 0)),     # expected
-            pl.BlockSpec((block, k), lambda i, s: (i, 0)),     # desired
-            pl.BlockSpec(memory_space=_ANY),                   # data
-            pl.BlockSpec(memory_space=_ANY),                   # version
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec((block, k), lambda i, s: (i, 0)),     # value at pt
-            pl.BlockSpec((block, 1), lambda i, s: (i, 0)),     # ver at pt
-            pl.BlockSpec((block, 1), lambda i, s: (i, 0)),     # success
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, k), data.dtype),
-            pltpu.VMEM((1, 1), jnp.uint32),
-            pltpu.SemaphoreType.DMA(()),
-        ],
-    )
-    new_data, new_ver, valpt, verpt, succ = pl.pallas_call(
-        _slow_kernel(n, pp, block),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n, k), data.dtype),
-            jax.ShapeDtypeStruct((n, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((pp, k), data.dtype),
-            jax.ShapeDtypeStruct((pp, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((pp, 1), jnp.int32),
-        ],
-        input_output_aliases={5: 0, 6: 1},
-        interpret=interpret,
-    )(s_slot, s_kind.reshape(pp, 1),
-      s_link_ver.reshape(pp, 1).astype(jnp.uint32), s_expected, s_desired,
-      data, version.reshape(n, 1))
-    return (new_data, new_ver.reshape(n), valpt[:p], verpt[:p, 0],
-            succ[:p, 0])
+    p, wr = s_slot.shape[0], _window_rows(k)
+    # Padding lanes are dead (slot n) and sort AFTER every live lane, so
+    # they never split a real segment.
+    s_slot, s_kind, s_link_ver, s_expected, s_desired = _pad_lanes(
+        block, n, s_slot, s_kind, s_link_ver, s_expected, s_desired)
+    new_cell = s_slot[1:] != s_slot[:-1]
+    edge = jnp.ones((1,), bool)
+    flags = (jnp.where(jnp.concatenate([edge, new_cell]), _SEG_START, 0)
+             | jnp.where(jnp.concatenate([new_cell, edge]), _SEG_END, 0))
+    meta = _lane_meta(n, k, s_slot, s_kind, s_link_ver, flags)
+    drows, vrows = _table_view(data, version)
+    scratch = [
+        pltpu.VMEM((wr, LANES), data.dtype),
+        pltpu.VMEM((1, LANES), jnp.uint32),
+        pltpu.SemaphoreType.DMA(()),
+    ]
+    out = _round_call(_slow_kernel(k, block), k, block, meta,
+                      _lane_rows(s_expected), _lane_rows(s_desired), drows,
+                      vrows, scratch, interpret)
+    return _lane_results(*out, meta, n, k, p)
 
 
 def _slow_pallas(n: int, data, version, ctx: LinkCtx, ops: OpBatch, *,

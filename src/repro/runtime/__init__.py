@@ -15,4 +15,4 @@ from repro.runtime.streams import (  # noqa: F401
     AdmissionStream, DecodeStream, InFlight, McasStream, SyntheticStream,
     serving_streams)
 from repro.runtime.faults import (  # noqa: F401
-    DATA_KINDS, SCHED_KINDS, Fault, FaultInjector)
+    DATA_KINDS, SCHED_KINDS, Fault, FaultInjector, IssueFault)

@@ -18,6 +18,7 @@ import jax
 from jax.sharding import Mesh
 
 from repro import dist
+from repro.launch.mesh import make_mesh
 
 logger = logging.getLogger(__name__)
 
@@ -62,10 +63,8 @@ def elastic_mesh(n_devices: int, *, model_parallel: int = 1,
             "is data=%d x model=%d%s)", plan.dropped, n_devices, plan.data,
             plan.model,
             f" under global_batch={global_batch}" if global_batch else "")
-    devs = jax.devices()[: plan.used]
-    import numpy as np
-    return Mesh(np.asarray(devs).reshape(plan.data, plan.model),
-                ("data", "model"))
+    return make_mesh((plan.data, plan.model), ("data", "model"),
+                     devices=jax.devices()[: plan.used])
 
 
 def reshard_state(state, cfg, mesh: Mesh):

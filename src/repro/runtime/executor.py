@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.core import engine
 from repro.obs.recorder import Recorder
+from repro.runtime import faults
 
 
 def _ops_np(ops: engine.OpBatch) -> engine.OpBatch:
@@ -361,7 +362,7 @@ class Executor:
             span = self.recorder.begin_issue(si, name)
             try:
                 h = self.target.issue(ops, self._ctx[si], donate=self.donate)
-            except Exception:
+            except faults.ISSUE_FAULTS:
                 # roll the stream back so the SAME batch retries after the
                 # backoff window; non-seekable streams can't retry
                 self.recorder.cancel_issue(span)

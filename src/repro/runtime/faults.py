@@ -57,11 +57,23 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 
 SCHED_KINDS = ("delay", "preempt", "shard_loss")
 DATA_KINDS = ("bit_flip", "torn_write", "stale_resurrect",
               "ckpt_corrupt", "ckpt_truncate")
+
+
+class IssueFault(RuntimeError):
+    """A transient failure of a target's issue path (a lost link, a
+    preempted host) that a retry of the same batch may clear."""
+
+
+# What the executor retries when `target.issue` raises: these and the
+# device runtime's own errors.  Anything else is a programming error and
+# propagates.
+ISSUE_FAULTS = (IssueFault, jax.errors.JaxRuntimeError)
 
 
 @dataclasses.dataclass(frozen=True)

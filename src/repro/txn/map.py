@@ -127,7 +127,7 @@ def make_map_txns(read_key, write_key, *, read_mask=None, write_mask=None,
                              f"({t}, {w}, vw)")
     try:
         wk, wm = np.asarray(write_key), np.asarray(write_mask)
-    except Exception:
+    except jax.errors.TracerArrayConversionError:
         wk = None
     if wk is not None:
         for i in range(t):
@@ -292,7 +292,7 @@ def transact_dist(mesh, dspec, dstate, txns: MapTxns, fn, *,
         st, res, _ovf = dsb.apply_hash(mesh, d, st, ops)
         # Materialize results on the host before the round logic reuses
         # them: the collective's outputs carry the mesh sharding (claimed
-        # replicated over spare axes under check_rep=False), and eager
+        # replicated over spare axes under check_vma=False), and eager
         # re-use in jnp ops would re-reduce those "replicas".
         return st, type(res)(*[np.asarray(x) for x in res])
 

@@ -129,7 +129,7 @@ def make_txns(slot, expected=None, desired=None, *, k: int) -> TxnBatch:
                              f"(mismatched k?)")
     try:
         slot_np = np.asarray(slot)          # concrete only; tracers skip
-    except Exception:
+    except jax.errors.TracerArrayConversionError:
         slot_np = None
     if slot_np is not None:
         for i in range(t):

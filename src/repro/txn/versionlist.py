@@ -112,7 +112,7 @@ def publish(spec: VersionSpec, state: VersionState, slots, values, ts
     (caller contract; history is never reordered)."""
     try:
         s_np = np.asarray(slots)
-    except Exception:
+    except jax.errors.TracerArrayConversionError:
         s_np = None
     if s_np is not None and len(np.unique(s_np)) != len(s_np):
         raise ValueError(f"publish slots must be distinct within one "

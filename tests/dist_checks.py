@@ -28,6 +28,7 @@ from oracle import (HashOracle, MapOracle, TableOracle, TxnOracle,  # noqa: E402
                     hash_batch, mixed_batch, txn_batch)
 from repro import atomics                                      # noqa: E402
 from repro.core import distributed as dsb                      # noqa: E402
+from repro.launch.mesh import make_mesh                        # noqa: E402
 
 SHARD_COUNTS = (2, 4, 8)
 
@@ -35,7 +36,7 @@ SHARD_COUNTS = (2, 4, 8)
 def _mesh(shards: int):
     """All scenarios run on the same 8-device fleet; unused devices
     replicate over the spare axis."""
-    return jax.make_mesh((shards, 8 // shards), ("shard", "rest"))
+    return make_mesh((shards, 8 // shards), ("shard", "rest"))
 
 
 def _drive_table(dspec, mesh, rng, init, steps, make_ops, msg):
@@ -466,7 +467,7 @@ def scenario_twolevel(strategy: str):
     variants (the tight caps force overflow at BOTH hops)."""
     rng = np.random.default_rng(zlib.crc32(strategy.encode()) ^ 0x2E11)
     n, k, pl = 48, 3, 4
-    mesh = jax.make_mesh((2, 4), ("node", "shard"))
+    mesh = make_mesh((2, 4), ("node", "shard"))
     for ilv in (False, True):
         for caps in ({}, dict(route_capacity=3, node_capacity=5)):
             dspec = dsb.DistSpec(
@@ -498,7 +499,7 @@ def scenario_executor(strategy: str):
         s = 1
         while s * 2 <= n_surviving and n % (s * 2) == 0:
             s *= 2
-        mesh = jax.make_mesh((s, 8 // s), ("shard", "rest"))
+        mesh = make_mesh((s, 8 // s), ("shard", "rest"))
         return mesh, dsb.DistSpec(
             atomics.AtomicSpec(n, k, strategy, p_max=64), "shard", s,
             32 // s)
@@ -540,7 +541,6 @@ def scenario_elastic(strategy: str):
     after it.  (b) The (params, opt) training state reshards through the
     same shrink/grow chain bit-identically, with `mesh_plan` reporting
     (never silently truncating) the devices each geometry drops."""
-    from jax.sharding import Mesh
     from repro.runtime import elastic_mesh, mesh_plan, reshard_dist, \
         reshard_state
 
@@ -549,7 +549,7 @@ def scenario_elastic(strategy: str):
     init = rng.integers(0, 2 ** 32, (n, k), dtype=np.uint32)
 
     def geo(s):
-        mesh = Mesh(np.asarray(jax.devices()[:s]), ("shard",))
+        mesh = make_mesh((s,), ("shard",), devices=jax.devices()[:s])
         return mesh, dsb.DistSpec(
             atomics.AtomicSpec(n, k, strategy, p_max=64), "shard", s, 8)
 

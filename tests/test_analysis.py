@@ -48,8 +48,9 @@ PROBE = textwrap.dedent("""
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.analysis import analyze_hlo
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     x = jax.ShapeDtypeStruct((128, 256), jnp.float32)
     def f(x, ws):
         def body(c, w):
@@ -73,9 +74,8 @@ PROBE = textwrap.dedent("""
     assert c.unknown_trip_whiles == 0
     assert set(c.coll_by_kind) == {"all-gather", "all-reduce"}
     # XLA's own cost_analysis counts the body ONCE (the undercount this
-    # module exists to fix); returns [dict] on some jax versions
-    ca = co.cost_analysis()
-    xla = (ca[0] if isinstance(ca, (list, tuple)) else ca)["flops"]
+    # module exists to fix)
+    xla = co.cost_analysis()["flops"]
     assert xla < c.flops / 6, (xla, c.flops)
     print("ANALYSIS_OK")
 """)

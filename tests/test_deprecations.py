@@ -6,7 +6,6 @@ run is warning-free."""
 
 import warnings
 
-import jax
 import numpy as np
 import pytest
 
@@ -16,6 +15,7 @@ from repro.core import deprecation
 from repro.core import distributed as dsb
 from repro.core import engine
 from repro.core import semantics as sem
+from repro.launch.mesh import make_mesh
 from repro.sync import llsc
 
 
@@ -83,7 +83,7 @@ def test_internal_sync_wrappers_are_warning_free():
 
 
 def test_distributed_shims_warn_once_and_still_work():
-    mesh = jax.make_mesh((1,), ("shard",))
+    mesh = make_mesh((1,), ("shard",))
     n, k, pl = 4, 2, 4
     deprecation.reset("core.distributed.init_sharded")
     deprecation.reset("core.distributed.make_apply")

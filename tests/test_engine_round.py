@@ -102,6 +102,25 @@ def test_round_matches_linearize_odd_width_and_padding(mode):
                         round_fn(data, ver, ctx, ops), "padding")
 
 
+@pytest.mark.parametrize("n,k", [(300, 4), (200, 3), (260, 128), (130, 130)])
+@pytest.mark.parametrize("spectrum", ["none", "low"])
+def test_round_matches_linearize_table_views(n, k, spectrum):
+    """The kernels see the table through lane-dense views: cells past the
+    first 128-lane window, n not a multiple of 128, a width padded to
+    Mosaic's row tiling (k=3), and rows of k >= 128 words (one or more
+    whole 128-lane rows per cell, zero-padded)."""
+    p = 24
+    rng = np.random.default_rng(n * 1000 + k)
+    data, ver = make_table(n, k, seed=n + k)
+    round_fn = engine_round.make_round(n, k, mode="pallas", interpret=True)
+    for trial in range(2):
+        ops, ctx = make_batch(rng, n, k, p, spectrum, data=data, ver=ver)
+        ref = engine.linearize(data, ver, ctx, ops)
+        assert_rounds_equal(ref, round_fn(data, ver, ctx, ops),
+                            f"n={n}/k={k}/{spectrum}/trial{trial}")
+        data, ver = ref[0], ref[1]
+
+
 @pytest.mark.parametrize("strategy", STRATEGIES)
 @pytest.mark.parametrize("spectrum", SPECTRA)
 def test_apply_matches_oracle_under_kernel_round(strategy, spectrum):

@@ -43,7 +43,7 @@ from repro.guard.scrub import ScrubReport, Scrubber, digest_np
 from repro.guard.scrub import scrub as scrub_pass
 from repro.guard.scrub import _cell_digest
 from repro.runtime.executor import Executor, LocalTarget
-from repro.runtime.faults import DATA_KINDS, Fault, FaultInjector
+from repro.runtime.faults import DATA_KINDS, Fault, FaultInjector, IssueFault
 from repro.runtime.streams import SyntheticStream
 from repro.sync.queue import BackoffPolicy
 
@@ -296,7 +296,7 @@ def test_issue_exception_retries_then_sheds(monkeypatch):
     def flaky_issue(ops, ctx, *, donate=True):
         if boom["left"] > 0:
             boom["left"] -= 1
-            raise RuntimeError("injected issue failure")
+            raise IssueFault("injected issue failure")
         return real_issue(ops, ctx, donate=donate)
 
     target.issue = flaky_issue

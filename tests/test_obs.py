@@ -177,10 +177,11 @@ def test_dist_counters_match_oracle_including_overflow(monkeypatch):
     overflow mask the linearization oracle uses (single-device mesh; the
     multi-host variant rides tests/dist_checks.py in CI)."""
     from repro.core import distributed as dsb
+    from repro.launch.mesh import make_mesh
     monkeypatch.setenv("BIGATOMIC_OBS", "counters")
     obs.reset()
     n, k, pl, cap = 16, 2, 8, 3
-    mesh = jax.make_mesh((1,), ("shard",))
+    mesh = make_mesh((1,), ("shard",))
     dspec = dsb.DistSpec(atomics.AtomicSpec(n, k, "cached_me", p_max=64),
                          "shard", 1, pl, route_capacity=cap)
     p = dspec.p_global
