@@ -1,14 +1,17 @@
-"""repro.obs smoke bench (ISSUE 9): drive the two observability tiers and
+"""repro.obs smoke bench: drive the counters and the profiler timeline and
 export both artifact kinds.
 
   * counters sweep — a fixed mixed LOAD/STORE/CAS + MCAS + queue workload
-    under BIGATOMIC_OBS=counters; the full snapshot (+ derived rates)
-    lands in benchmarks/results/obs_metrics.jsonl.
+    under BIGATOMIC_OBS=counters; the full snapshot (+ derived rates, +
+    the executor's host counters) lands in
+    benchmarks/results/obs_metrics.jsonl.
   * trace run — an oversubscribed executor with an injected straggler
-    delay, recorded span-by-span; the Chrome-trace/Perfetto timeline
-    lands in benchmarks/results/obs_trace.json.
+    delay under `jax.profiler.trace`: its `executor.*` spans, the
+    `atomics.apply` spans inside them and the round program's device ops
+    land in benchmarks/results/obs_trace/ (TensorBoard's profile plugin
+    or Perfetto reads it).
 
-CI's `obs` job runs this with --quick and uploads both files as workflow
+CI's `obs` job runs this with --quick and uploads both as workflow
 artifacts.
 """
 
@@ -18,6 +21,7 @@ import contextlib
 import os
 
 RESULTS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "results")
+TRACE_DIR = os.path.join(RESULTS, "obs_trace")
 
 
 @contextlib.contextmanager
@@ -88,8 +92,10 @@ def counters_sweep(quick: bool = False) -> dict:
 
 
 def trace_run(quick: bool = False):
-    """One oversubscribed executor run with a straggler fault, recorded in
-    the span tier; returns the Recorder."""
+    """One oversubscribed executor run with a straggler fault, under the
+    JAX profiler (trace in TRACE_DIR); returns the Recorder."""
+    import jax
+
     from repro import atomics
     from repro.obs import Recorder
     from repro.runtime import (Executor, Fault, FaultInjector, LocalTarget,
@@ -102,13 +108,14 @@ def trace_run(quick: bool = False):
                                n_batches=n_batches, hot_cells=4,
                                hot_frac=0.25)
                for i in range(4)]
-    rcd = Recorder(trace=True)
+    rcd = Recorder()
     ex = Executor(target, streams, slots=2, oversubscription=2,
                   injector=FaultInjector([Fault(round=2, kind="delay",
                                                 stream=1, seconds=0.01,
                                                 rounds=3)]),
                   recorder=rcd)
-    ex.run()
+    with jax.profiler.trace(TRACE_DIR):
+        ex.run()
     return rcd
 
 
@@ -121,8 +128,6 @@ def main(quick: bool = False) -> None:
         rcd = trace_run(quick)
         metrics_path = os.path.join(RESULTS, "obs_metrics.jsonl")
         obs.write_metrics_jsonl(metrics_path, extra=rcd.metrics())
-        trace_path = os.path.join(RESULTS, "obs_trace.json")
-        obs.write_chrome_trace(rcd, trace_path)
 
     rates = obs.derived(snap)
     print(f"  engine batches      {snap['engine.batches']}")
@@ -130,9 +135,9 @@ def main(quick: bool = False) -> None:
     print(f"  mean slow rounds    {rates['mean_slow_rounds']:.2f}")
     print(f"  mcas commits/aborts {snap['mcas.commits']}/{snap['mcas.aborts']}")
     print(f"  queue rounds        {snap.get('queue.rounds', 0)}")
-    print(f"  trace events        {len(rcd.events)}")
+    print(f"  executor issues     {rcd.metrics()['exec.issues']}")
     print(f"  wrote {metrics_path}")
-    print(f"  wrote {trace_path}")
+    print(f"  wrote {TRACE_DIR}")
 
 
 if __name__ == "__main__":

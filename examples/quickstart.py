@@ -72,10 +72,11 @@ rates = obs.derived(snap)      # hit_rate_fast / eligible_rate / mean_slow_round
 print("engine.batches:", snap["engine.batches"],
       "| fast-path hit rate:", round(rates["hit_rate_fast"], 2),
       "| cas failures:", snap["engine.fail.cas"])
-# The executor timeline tier: pass obs.Recorder(trace=True) to
-# runtime.Executor and export with obs.write_chrome_trace(rcd, path) —
-# one Perfetto track per logical stream, one per device slot.  The full
-# metric-name table lives in DESIGN.md §10.
+# Timelines come from the JAX profiler: under jax.profiler.trace(dir),
+# every atomics.apply call records its host spans, runtime.Executor its
+# executor.issue/recover/scrub/checkpoint spans, and the device ops carry
+# the round's engine.* scopes, all on one clock.  The full metric-name
+# table lives in DESIGN.md §10.
 os.environ.pop("BIGATOMIC_OBS")
 
 # --- fault tolerance: the §11 guard, on demand -----------------------------

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.core import registry
 from repro.core.layout import WORD_DTYPE
@@ -69,6 +70,21 @@ DELETE = 9
 
 TABLE_KINDS = (LOAD, STORE, CAS, IDLE, LL, SC, VALIDATE)
 HASH_KINDS = (FIND, INSERT, DELETE, IDLE)
+
+# Names in the profiler's trace.  Host spans (`jax.profiler.TraceAnnotation`)
+# of `apply`, one per step of the call:
+SPAN_APPLY = "atomics.apply"
+SPAN_VALIDATE = "atomics.apply.validate"      # check_kinds
+SPAN_TO_DEVICE = "atomics.apply.to_device"    # canonicalize_ops / _ctx
+SPAN_LAUNCH = "atomics.apply.launch"          # mode, telemetry, jit call
+# Device scopes (`jax.named_scope`) of the round program, siblings (never
+# nested), so each device op carries at most one of them:
+SCOPE_PREDICATE = "engine.predicate"   # fast_path_ok
+SCOPE_SORT = "engine.sort"             # slow tier: argsorts, sorted gathers
+SCOPE_FAST = "engine.fast_round"       # fast tier: kernel or gather/scatter
+SCOPE_SLOW = "engine.slow_round"       # slow tier: kernel or combining rounds
+SCOPE_RESULTS = "engine.results"       # ctx, result and stats rebuild
+SCOPE_COMMIT = "engine.commit"         # the strategy's layout commit
 
 
 class OpBatch(NamedTuple):
@@ -348,35 +364,38 @@ def linearize(data: jax.Array, version: jax.Array, ctx: LinkCtx,
     # Inactive lanes get an out-of-range slot so they can never collide.
     slot = jnp.where(active, ops.slot, n)
 
-    order = jnp.argsort(slot, stable=True)  # (slot, lane) lexicographic
-    inv = jnp.argsort(order, stable=True)
+    with jax.named_scope(SCOPE_SORT):
+        order = jnp.argsort(slot, stable=True)  # (slot, lane) lexicographic
+        inv = jnp.argsort(order, stable=True)
 
-    s_slot = slot[order]
-    s_kind = kind[order]
-    s_expected = ops.expected[order]
-    s_desired = ops.desired[order]
-    s_cslot = ctx.slot[order]
-    s_cver = ctx.version[order]
-    s_clnk = ctx.linked[order]
+        s_slot = slot[order]
+        s_kind = kind[order]
+        s_expected = ops.expected[order]
+        s_desired = ops.desired[order]
+        s_cslot = ctx.slot[order]
+        s_cver = ctx.version[order]
+        s_clnk = ctx.linked[order]
 
-    idx = jnp.arange(p, dtype=jnp.int32)
-    seg_start = jnp.concatenate(
-        [jnp.ones((1,), bool), s_slot[1:] != s_slot[:-1]])
-    start_idx = _segmented_scan_max(jnp.where(seg_start, idx, -1), seg_start)
+    with jax.named_scope(SCOPE_SLOW):
+        idx = jnp.arange(p, dtype=jnp.int32)
+        seg_start = jnp.concatenate(
+            [jnp.ones((1,), bool), s_slot[1:] != s_slot[:-1]])
+        start_idx = _segmented_scan_max(jnp.where(seg_start, idx, -1),
+                                        seg_start)
 
-    is_valcas = (s_kind == STORE) | (s_kind == CAS)
-    is_sc = (s_kind == SC) & (s_slot < n)
-    is_upd = is_valcas | is_sc
-    # Exclusive count of updates before each position, segment-scoped.
-    cum_upd = jnp.cumsum(is_upd.astype(jnp.int32))
-    excl_upd = cum_upd - is_upd.astype(jnp.int32)
-    upd_rank = excl_upd - excl_upd[start_idx]
-    n_rounds = jnp.where(jnp.any(is_upd),
-                         jnp.max(jnp.where(is_upd, upd_rank, -1)) + 1, 0)
+        is_valcas = (s_kind == STORE) | (s_kind == CAS)
+        is_sc = (s_kind == SC) & (s_slot < n)
+        is_upd = is_valcas | is_sc
+        # Exclusive count of updates before each position, segment-scoped.
+        cum_upd = jnp.cumsum(is_upd.astype(jnp.int32))
+        excl_upd = cum_upd - is_upd.astype(jnp.int32)
+        upd_rank = excl_upd - excl_upd[start_idx]
+        n_rounds = jnp.where(jnp.any(is_upd),
+                             jnp.max(jnp.where(is_upd, upd_rank, -1)) + 1, 0)
 
-    safe_slot = jnp.minimum(s_slot, n - 1)
-    init_vals = data[safe_slot]          # pre-batch values per lane
-    ver0 = version[safe_slot]            # pre-batch versions per lane
+        safe_slot = jnp.minimum(s_slot, n - 1)
+        init_vals = data[safe_slot]          # pre-batch values per lane
+        ver0 = version[safe_slot]            # pre-batch versions per lane
 
     def _general(data, version):
         """L-round combining loop: round t applies the t-th write of every
@@ -451,31 +470,33 @@ def linearize(data: jax.Array, version: jax.Array, ctx: LinkCtx,
         new_version = version.at[w_idx].add(jnp.uint32(2), mode="drop")
         return new_data, new_version, val_s, verpt_s, win
 
-    new_data, new_version, val_s, verpt_s, succ_s = lax.cond(
-        jnp.any(is_valcas), _general, _fast, data, version)
+    with jax.named_scope(SCOPE_SLOW):
+        new_data, new_version, val_s, verpt_s, succ_s = lax.cond(
+            jnp.any(is_valcas), _general, _fast, data, version)
 
-    # --- per-lane results ---------------------------------------------------
-    is_read = (s_kind == LOAD) | (s_kind == LL)
-    vl_ok = s_clnk & (s_cslot == s_slot) & (s_cver == verpt_s)
-    s_success = jnp.where(
-        is_read | (s_kind == STORE), s_slot < n,
-        jnp.where(s_kind == VALIDATE, vl_ok,
-                  jnp.where(is_upd, succ_s, False)))
-    s_value = jnp.where((s_kind != IDLE)[:, None], val_s,
-                        jnp.zeros_like(val_s))
+    with jax.named_scope(SCOPE_RESULTS):
+        # --- per-lane results -----------------------------------------------
+        is_read = (s_kind == LOAD) | (s_kind == LL)
+        vl_ok = s_clnk & (s_cslot == s_slot) & (s_cver == verpt_s)
+        s_success = jnp.where(
+            is_read | (s_kind == STORE), s_slot < n,
+            jnp.where(s_kind == VALIDATE, vl_ok,
+                      jnp.where(is_upd, succ_s, False)))
+        s_value = jnp.where((s_kind != IDLE)[:, None], val_s,
+                            jnp.zeros_like(val_s))
 
-    # --- link context updates ----------------------------------------------
-    is_ll = (s_kind == LL) & (s_slot < n)
-    n_slot = jnp.where(is_ll, s_slot, s_cslot)
-    n_ver = jnp.where(is_ll, verpt_s, s_cver)
-    n_val = jnp.where(is_ll[:, None], val_s, ctx.value[order])
-    n_lnk = jnp.where(is_ll, True,
-                      jnp.where(s_kind == SC, False, s_clnk))
-    new_ctx = LinkCtx(n_slot[inv], n_ver[inv], n_val[inv], n_lnk[inv])
-    result = ApplyResult(s_value[inv], s_success[inv])
+        # --- link context updates -------------------------------------------
+        is_ll = (s_kind == LL) & (s_slot < n)
+        n_slot = jnp.where(is_ll, s_slot, s_cslot)
+        n_ver = jnp.where(is_ll, verpt_s, s_cver)
+        n_val = jnp.where(is_ll[:, None], val_s, ctx.value[order])
+        n_lnk = jnp.where(is_ll, True,
+                          jnp.where(s_kind == SC, False, s_clnk))
+        new_ctx = LinkCtx(n_slot[inv], n_ver[inv], n_val[inv], n_lnk[inv])
+        result = ApplyResult(s_value[inv], s_success[inv])
 
-    # --- stats (the shared sorted-order definition) --------------------------
-    stats = stats_on_sorted(n, s_slot, s_kind, succ_s)
+        # --- stats (the shared sorted-order definition) ----------------------
+        stats = stats_on_sorted(n, s_slot, s_kind, succ_s)
     return new_data, new_version, new_ctx, result, stats
 
 
@@ -590,8 +611,9 @@ def _apply_impl(spec: AtomicSpec, state, ops: OpBatch, ctx: LinkCtx | None,
     round_fn = round_for(spec, impl, mode)
     new_data, new_version, new_ctx, result, stats = round_fn(
         impl.engine_view(state), state.version, ctx, ops)
-    new_state = impl.commit(state, new_data, new_version,
-                            stats.n_updates, ops.p)
+    with jax.named_scope(SCOPE_COMMIT):
+        new_state = impl.commit(state, new_data, new_version,
+                                stats.n_updates, ops.p)
     traffic = impl.traffic(stats, spec.k, ops.p)
     if telem is None:
         # BIGATOMIC_OBS=off: None is an empty pytree, so this traces the
@@ -635,25 +657,37 @@ def apply(spec: AtomicSpec, state, ops: OpBatch, ctx: LinkCtx | None = None,
     be reused afterwards.  Donation is skipped on CPU backends, which
     cannot donate.
 
+    The call records host spans in the profiler's trace: `atomics.apply`
+    around its three steps `.validate`, `.to_device` and `.launch` (up to
+    the jitted round's return, not its completion).  With no profiler
+    running each costs about a microsecond.  Under an outer jit they time
+    tracing, not execution, and the device work shows only under the
+    round's `engine.*` scopes.
+
     Returns (state', ctx', ApplyResult, ApplyStats, Traffic)."""
-    check_kinds(ops.kind, TABLE_KINDS, "table")
-    ops = canonicalize_ops(ops)
-    if ctx is not None:
-        ctx = canonicalize_ctx(ctx)
-    mode = _engine_round().configured_mode()
-    # Under BIGATOMIC_OBS=counters the global counter pytree rides the same
-    # jit call as one extra argument/output (no extra dispatch); when off —
-    # or when an outer jit owns this call — telem is None and the traced
-    # program is byte-identical to the pre-observability one.
-    telem = obs_telemetry.carry_in(state, ops.kind)
-    fn = (_apply_donated if donate and jax.default_backend() != "cpu"
-          else _apply)
-    out = fn(spec, state, ops, ctx, mode, telem)
-    if telem is not None:
-        *out, telem = out
-        obs_telemetry.carry_out(telem)
-        return tuple(out)
-    return out
+    with TraceAnnotation(SPAN_APPLY):
+        with TraceAnnotation(SPAN_VALIDATE):
+            check_kinds(ops.kind, TABLE_KINDS, "table")
+        with TraceAnnotation(SPAN_TO_DEVICE):
+            ops = canonicalize_ops(ops)
+            if ctx is not None:
+                ctx = canonicalize_ctx(ctx)
+        with TraceAnnotation(SPAN_LAUNCH):
+            mode = _engine_round().configured_mode()
+            # Under BIGATOMIC_OBS=counters the global counter pytree rides
+            # the same jit call as one extra argument/output (no extra
+            # dispatch); when off -- or when an outer jit owns this call --
+            # telem is None and the traced program is byte-identical to the
+            # pre-observability one.
+            telem = obs_telemetry.carry_in(state, ops.kind)
+            fn = (_apply_donated if donate and jax.default_backend() != "cpu"
+                  else _apply)
+            out = fn(spec, state, ops, ctx, mode, telem)
+        if telem is not None:
+            *out, telem = out
+            obs_telemetry.carry_out(telem)
+            return tuple(out)
+        return out
 
 
 class RoundHandle:

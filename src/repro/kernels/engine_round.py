@@ -67,6 +67,10 @@ _ANY = pltpu.MemorySpace.ANY
 # Lanes per grid step: the fast tier keeps this many window DMAs in flight.
 DEFAULT_BLOCK = 8
 
+# The kernels' names in the profiler's trace and in compiler messages.
+FAST_KERNEL = "engine_fast_round"
+SLOW_KERNEL = "engine_slow_round"
+
 _MODES = ("auto", "pallas", "xla", "off")
 
 
@@ -110,16 +114,17 @@ def fast_path_ok(n: int, ops: OpBatch) -> jax.Array:
     lane counts, then a max).  False positives are impossible by
     construction: a colliding batch with any write fails (b), so it can
     never take the fast kernel."""
-    kind, slot = ops.kind, ops.slot
-    active = kind != IDLE
-    in_range = (slot >= 0) & (slot < n)
-    all_in = ~jnp.any(active & ~in_range)
-    is_write = active & ((kind == STORE) | (kind == CAS) | (kind == SC))
-    read_only = ~jnp.any(is_write)
-    cslot = jnp.where(active & in_range, slot, n)
-    counts = jnp.zeros((n + 1,), jnp.int32).at[cslot].add(1, mode="drop")
-    no_dup = jnp.max(counts[:n], initial=0) <= 1
-    return all_in & (read_only | no_dup)
+    with jax.named_scope(engine.SCOPE_PREDICATE):
+        kind, slot = ops.kind, ops.slot
+        active = kind != IDLE
+        in_range = (slot >= 0) & (slot < n)
+        all_in = ~jnp.any(active & ~in_range)
+        is_write = active & ((kind == STORE) | (kind == CAS) | (kind == SC))
+        read_only = ~jnp.any(is_write)
+        cslot = jnp.where(active & in_range, slot, n)
+        counts = jnp.zeros((n + 1,), jnp.int32).at[cslot].add(1, mode="drop")
+        no_dup = jnp.max(counts[:n], initial=0) <= 1
+        return all_in & (read_only | no_dup)
 
 
 def path_counts(n: int, ops: OpBatch, *, fused: bool):
@@ -192,20 +197,22 @@ def _assemble_fast(n: int, ctx: LinkCtx, ops: OpBatch, link_ver, cur, ver,
 def _fast_xla(n: int, data, version, ctx: LinkCtx, ops: OpBatch):
     """Pure-XLA fast path: one gather, register math, one scatter.  No sort,
     no scans, no rounds — the off-TPU production fast path."""
-    kind, slot = ops.kind, ops.slot
-    active = kind != IDLE
-    safe = jnp.clip(slot, 0, n - 1)
-    cur = data[safe]
-    ver = version[safe]
-    match = jnp.all(cur == ops.expected, axis=1)
-    link_ver = _poisoned_link_ver(ctx, slot)
-    okw = active & ((kind == STORE) | ((kind == CAS) & match)
-                    | ((kind == SC) & (link_ver == ver)))
-    w_idx = jnp.where(okw, slot, n)
-    new_data = data.at[w_idx].set(ops.desired, mode="drop")
-    new_version = version.at[w_idx].add(jnp.uint32(2), mode="drop")
-    return _assemble_fast(n, ctx, ops, link_ver, cur, ver, okw,
-                          new_data, new_version)
+    with jax.named_scope(engine.SCOPE_FAST):
+        kind, slot = ops.kind, ops.slot
+        active = kind != IDLE
+        safe = jnp.clip(slot, 0, n - 1)
+        cur = data[safe]
+        ver = version[safe]
+        match = jnp.all(cur == ops.expected, axis=1)
+        link_ver = _poisoned_link_ver(ctx, slot)
+        okw = active & ((kind == STORE) | ((kind == CAS) & match)
+                        | ((kind == SC) & (link_ver == ver)))
+        w_idx = jnp.where(okw, slot, n)
+        new_data = data.at[w_idx].set(ops.desired, mode="drop")
+        new_version = version.at[w_idx].add(jnp.uint32(2), mode="drop")
+    with jax.named_scope(engine.SCOPE_RESULTS):
+        return _assemble_fast(n, ctx, ops, link_ver, cur, ver, okw,
+                              new_data, new_version)
 
 
 # ---------------------------------------------------------------------------
@@ -398,8 +405,8 @@ def _copy(src, dst, sem):
     cp.wait()
 
 
-def _round_call(kernel, k: int, block: int, meta, exp_rows, des_rows, drows,
-                vrows, scratch, interpret: bool):
+def _round_call(kernel, name: str, k: int, block: int, meta, exp_rows,
+                des_rows, drows, vrows, scratch, interpret: bool):
     pp, wr = meta.shape[0], _window_rows(k)
     tile = pl.BlockSpec((block * wr, LANES), lambda i: (i, 0))
     table = pl.BlockSpec(memory_space=_ANY)
@@ -430,6 +437,7 @@ def _round_call(kernel, k: int, block: int, meta, exp_rows, des_rows, drows,
         # the table is updated in place: data = input 3, version = input 4
         input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
+        name=name,
     )(meta, exp_rows, des_rows, drows, vrows)
 
 
@@ -520,7 +528,7 @@ def fast_round_pallas(data, version, slot, kind, link_ver, expected, desired,
         pltpu.SemaphoreType.DMA((block,)),
         pltpu.SemaphoreType.DMA(()),
     ]
-    out = _round_call(_fast_kernel(k, block), k, block, meta,
+    out = _round_call(_fast_kernel(k, block), FAST_KERNEL, k, block, meta,
                       _lane_rows(expected), _lane_rows(desired), drows, vrows,
                       scratch, interpret)
     return _lane_results(*out, meta, n, k, p)
@@ -528,13 +536,15 @@ def fast_round_pallas(data, version, slot, kind, link_ver, expected, desired,
 
 def _fast_pallas(n: int, data, version, ctx: LinkCtx, ops: OpBatch, *,
                  block: int, interpret: bool):
-    slot = jnp.where(ops.kind != IDLE, ops.slot, n)
-    link_ver = _poisoned_link_ver(ctx, ops.slot)
-    new_data, new_version, wit, verpt, okw = fast_round_pallas(
-        data, version, slot, ops.kind, link_ver, ops.expected, ops.desired,
-        block=block, interpret=interpret)
-    return _assemble_fast(n, ctx, ops, link_ver, wit, verpt, okw != 0,
-                          new_data, new_version)
+    with jax.named_scope(engine.SCOPE_FAST):
+        slot = jnp.where(ops.kind != IDLE, ops.slot, n)
+        link_ver = _poisoned_link_ver(ctx, ops.slot)
+        new_data, new_version, wit, verpt, okw = fast_round_pallas(
+            data, version, slot, ops.kind, link_ver, ops.expected,
+            ops.desired, block=block, interpret=interpret)
+    with jax.named_scope(engine.SCOPE_RESULTS):
+        return _assemble_fast(n, ctx, ops, link_ver, wit, verpt, okw != 0,
+                              new_data, new_version)
 
 
 # ---------------------------------------------------------------------------
@@ -627,7 +637,7 @@ def slow_round_pallas(data, version, s_slot, s_kind, s_link_ver, s_expected,
         pltpu.VMEM((1, LANES), jnp.uint32),
         pltpu.SemaphoreType.DMA(()),
     ]
-    out = _round_call(_slow_kernel(k, block), k, block, meta,
+    out = _round_call(_slow_kernel(k, block), SLOW_KERNEL, k, block, meta,
                       _lane_rows(s_expected), _lane_rows(s_desired), drows,
                       vrows, scratch, interpret)
     return _lane_results(*out, meta, n, k, p)
@@ -637,35 +647,37 @@ def _slow_pallas(n: int, data, version, ctx: LinkCtx, ops: OpBatch, *,
                  block: int, interpret: bool):
     """Sort once, replay in one kernel pass, then rebuild ctx/result/stats
     exactly as `linearize` defines them (two cheap scans; no while_loop)."""
-    p, k = ops.desired.shape
     kind = ops.kind
     active = kind != IDLE
     slot = jnp.where(active, ops.slot, n)
-    order = jnp.argsort(slot, stable=True)
-    inv = jnp.argsort(order, stable=True)
+    with jax.named_scope(engine.SCOPE_SORT):
+        order = jnp.argsort(slot, stable=True)
+        inv = jnp.argsort(order, stable=True)
+        s_slot = slot[order]
+        s_kind = kind[order]
+        s_link_ver = _poisoned_link_ver(ctx, ops.slot)[order]
+        s_expected, s_desired = ops.expected[order], ops.desired[order]
 
-    s_slot = slot[order]
-    s_kind = kind[order]
-    s_link_ver = _poisoned_link_ver(ctx, ops.slot)[order]
+    with jax.named_scope(engine.SCOPE_SLOW):
+        new_data, new_version, val_s, verpt_s, succ_i = slow_round_pallas(
+            data, version, s_slot, s_kind, s_link_ver, s_expected, s_desired,
+            block=block, interpret=interpret)
 
-    new_data, new_version, val_s, verpt_s, succ_i = slow_round_pallas(
-        data, version, s_slot, s_kind, s_link_ver, ops.expected[order],
-        ops.desired[order], block=block, interpret=interpret)
-    s_success = succ_i != 0
+    with jax.named_scope(engine.SCOPE_RESULTS):
+        s_success = succ_i != 0
+        is_ll = (s_kind == LL) & (s_slot < n)
+        n_slot = jnp.where(is_ll, s_slot, ctx.slot[order])
+        n_ver = jnp.where(is_ll, verpt_s, ctx.version[order])
+        n_val = jnp.where(is_ll[:, None], val_s, ctx.value[order])
+        n_lnk = jnp.where(is_ll, True,
+                          jnp.where(s_kind == SC, False, ctx.linked[order]))
+        new_ctx = LinkCtx(n_slot[inv], n_ver[inv], n_val[inv], n_lnk[inv])
+        s_value = jnp.where((s_kind != IDLE)[:, None], val_s,
+                            jnp.zeros_like(val_s))
+        result = ApplyResult(s_value[inv], s_success[inv])
 
-    is_ll = (s_kind == LL) & (s_slot < n)
-    n_slot = jnp.where(is_ll, s_slot, ctx.slot[order])
-    n_ver = jnp.where(is_ll, verpt_s, ctx.version[order])
-    n_val = jnp.where(is_ll[:, None], val_s, ctx.value[order])
-    n_lnk = jnp.where(is_ll, True,
-                      jnp.where(s_kind == SC, False, ctx.linked[order]))
-    new_ctx = LinkCtx(n_slot[inv], n_ver[inv], n_val[inv], n_lnk[inv])
-    s_value = jnp.where((s_kind != IDLE)[:, None], val_s,
-                        jnp.zeros_like(val_s))
-    result = ApplyResult(s_value[inv], s_success[inv])
-
-    # Stats: the single sorted-order definition shared with `linearize`.
-    stats = engine.stats_on_sorted(n, s_slot, s_kind, s_success)
+        # Stats: the single sorted-order definition shared with `linearize`.
+        stats = engine.stats_on_sorted(n, s_slot, s_kind, s_success)
     return new_data, new_version, new_ctx, result, stats
 
 

@@ -1,23 +1,24 @@
-"""repro.obs — two-tier observability (DESIGN.md §10).
+"""repro.obs — observability (DESIGN.md §10).
 
-Tier 1 (`obs.telemetry`): in-graph int32 counters accumulated inside the
-existing jitted programs, gated by BIGATOMIC_OBS=off|counters|trace so
-`off` compiles to the exact pre-observability programs.
+Counters (`obs.telemetry`): in-graph int32 counters accumulated inside the
+existing jitted programs, gated by BIGATOMIC_OBS=off|counters so `off`
+compiles to the exact pre-observability programs.  `obs.recorder` keeps
+the executor's host counters and the issue latencies its straggler
+watchdog reads; `obs.export` writes every counter as JSONL.
 
-Tier 2 (`obs.recorder` + `obs.export`): the host-side executor timeline —
-Chrome-trace/Perfetto spans per logical stream and per device slot, plus
-a JSONL metrics sink with a stable name schema.
+Timelines come from the JAX profiler (`jax.profiler.trace`): the entry
+points and the executor record host spans and the engine round names its
+device scopes there, all on one clock (DESIGN.md §10).
 """
 
-from repro.obs.export import (chrome_trace, write_chrome_trace,
-                              write_metrics_jsonl)
+from repro.obs.export import write_metrics_jsonl
 from repro.obs.recorder import Recorder
 from repro.obs.telemetry import (Telemetry, configured_mode, counters_on,
                                  derived, init_telemetry, record, reset,
-                                 snapshot, trace_on)
+                                 snapshot)
 
 __all__ = [
-    "Telemetry", "configured_mode", "counters_on", "trace_on",
+    "Telemetry", "configured_mode", "counters_on",
     "init_telemetry", "record", "reset", "snapshot", "derived",
-    "Recorder", "chrome_trace", "write_chrome_trace", "write_metrics_jsonl",
+    "Recorder", "write_metrics_jsonl",
 ]

@@ -1,9 +1,4 @@
-"""Serialization for the observability subsystem: Chrome-trace/Perfetto
-JSON for `Recorder` timelines, JSONL for counter snapshots.
-
-The trace format is the Chrome trace-event JSON object form — loadable in
-Perfetto (ui.perfetto.dev) and chrome://tracing.  The metrics sink is one
-JSON object per line with the stable schema
+"""The counter sink: one JSON object per line with the stable schema
 
     {"metric": "<name from DESIGN.md §10>", "value": <int|float>}
 
@@ -15,27 +10,7 @@ from __future__ import annotations
 
 import json
 
-from repro.obs.recorder import PID_SLOTS, PID_STREAMS
 from repro.obs.telemetry import derived, snapshot
-
-
-def chrome_trace(recorder) -> dict:
-    """The full Chrome-trace document for a `Recorder`: process metadata
-    for the two track groups plus every recorded event."""
-    events = [
-        {"ph": "M", "name": "process_name", "pid": PID_STREAMS,
-         "args": {"name": "logical streams"}},
-        {"ph": "M", "name": "process_name", "pid": PID_SLOTS,
-         "args": {"name": "device slots"}},
-    ]
-    events.extend(recorder.events)
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-def write_chrome_trace(recorder, path: str) -> None:
-    with open(path, "w") as f:
-        json.dump(chrome_trace(recorder), f)
-        f.write("\n")
 
 
 def write_metrics_jsonl(path: str, extra: dict | None = None) -> None:

@@ -19,7 +19,10 @@ The gate is the static BIGATOMIC_OBS flag:
             dispatch per collective round), and host-side retry loops
             (`sync.queue`, `serving.engine`) record into a host counter
             dict.
-  trace     counters + the tier-2 executor timeline (`obs.recorder`).
+
+Timelines are not this flag's business: the entry points and the executor
+write host spans and device scopes into the JAX profiler's trace
+(`jax.profiler.trace`), on one clock with the device's ops.
 
 Like BIGATOMIC_ENGINE_KERNEL, the flag is read per call and threaded as a
 static jit argument (or None-vs-pytree structure), so flipping it
@@ -47,7 +50,7 @@ import numpy as np
 N_KINDS = 10          # engine.LOAD .. engine.DELETE
 N_HIST = 16           # log2 contention buckets: [1], [2,3], [4,7], ...
 
-_MODES = ("off", "counters", "trace")
+_MODES = ("off", "counters")
 
 _KIND_NAMES = ("load", "store", "cas", "idle", "ll", "sc", "validate",
                "find", "insert", "delete")
@@ -65,10 +68,6 @@ def configured_mode() -> str:
 
 def counters_on() -> bool:
     return configured_mode() != "off"
-
-
-def trace_on() -> bool:
-    return configured_mode() == "trace"
 
 
 class Telemetry(NamedTuple):

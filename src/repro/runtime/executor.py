@@ -35,6 +35,11 @@ module is that regime as a scheduler:
                `replay_executor_history` replays the whole multi-stream
                interleaving — including across a recovery boundary —
                through one sequential oracle.
+  timeline     each issue, recovery, scrub and checkpoint is a host span
+               in the JAX profiler's trace (`executor.issue` with its
+               stream's name, `executor.recover`, `executor.scrub`,
+               `executor.checkpoint`); an ops issue's `atomics.apply`
+               spans nest inside its `executor.issue`.
 
 Nothing here blocks except retirement past the in-flight budget and the
 explicit drains at checkpoint/recovery boundaries.
@@ -48,10 +53,17 @@ import time
 from collections import deque
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import engine
 from repro.obs.recorder import Recorder
 from repro.runtime import faults
+
+
+SPAN_ISSUE = "executor.issue"
+SPAN_RECOVER = "executor.recover"
+SPAN_SCRUB = "executor.scrub"
+SPAN_CHECKPOINT = "executor.checkpoint"
 
 
 def _ops_np(ops: engine.OpBatch) -> engine.OpBatch:
@@ -227,9 +239,8 @@ class Executor:
                       deprioritized (skip their next slot).
     recorder:         `obs.Recorder` sink for round/issue/lifecycle events
                       (a fresh one is built if omitted).  It owns the
-                      issue-latency bookkeeping feeding the watchdog and,
-                      under BIGATOMIC_OBS=trace, the Chrome-trace span
-                      timeline (`obs.chrome_trace`).
+                      event counts and the issue-latency bookkeeping
+                      feeding the watchdog.
     guard:            `PreemptionGuard` (or compatible) polled at round
                       boundaries; `request_stop()` drains + checkpoints.
     injector:         `faults.FaultInjector`, polled before every issue
@@ -319,14 +330,13 @@ class Executor:
     # -- issue / retire ------------------------------------------------------
 
     def _retire_one(self) -> None:
-        rec, h, stream, tok = self._inflight.popleft()
+        rec, h, stream = self._inflight.popleft()
+        self.recorder.retire()
         if hasattr(h, "finish"):                 # host-stream token
             h.finish()
-            self.recorder.end_issue(tok)
             return
         h.wait()
         if rec is None:                          # "round" stream step
-            self.recorder.end_issue(tok)
             return
         rec.value = np.asarray(h.result.value)
         rec.success = np.asarray(h.result.success)
@@ -334,7 +344,6 @@ class Executor:
         rec.overflow = None if ovf is None else np.asarray(ovf)
         if self.scrubber is not None:
             self.scrubber.note_results(rec.ops, rec.success)
-        self.recorder.end_issue(tok, args={"seq": rec.seq})
         stream.deliver(rec.seq, rec.value, rec.success, rec.overflow)
 
     def _drain(self) -> None:
@@ -347,6 +356,16 @@ class Executor:
 
     def _issue(self, si: int, stream) -> bool:
         name = getattr(stream, "name", None) or f"s{si}"
+        with TraceAnnotation(SPAN_ISSUE, stream=name):
+            if not self._enqueue(si, stream):
+                return False
+        self.issues += 1
+        self._trim()
+        return True
+
+    def _enqueue(self, si: int, stream) -> bool:
+        """Issue one step of `stream` into the in-flight window; False if
+        it had nothing to issue (or its issue failed and will retry)."""
         if stream.kind == "ops":
             ops = stream.next_batch()
             if ops is None:
@@ -359,13 +378,11 @@ class Executor:
                 ops, poisoned = self.scrubber.mask_ops(ops)
             seq = self._seq[si]
             self._seq[si] += 1
-            span = self.recorder.begin_issue(si, name)
             try:
                 h = self.target.issue(ops, self._ctx[si], donate=self.donate)
             except faults.ISSUE_FAULTS:
                 # roll the stream back so the SAME batch retries after the
                 # backoff window; non-seekable streams can't retry
-                self.recorder.cancel_issue(span)
                 self._seq[si] = seq
                 if not hasattr(stream, "seek"):
                     raise
@@ -376,7 +393,7 @@ class Executor:
             rec = IssueRec(si, seq, _ops_np(ops),
                            order=getattr(h, "order", None))
             self.history.append(rec)
-            self._inflight.append((rec, h, stream, span))
+            self._inflight.append((rec, h, stream))
             if poisoned is not None and \
                     not (np.asarray(ops.kind) != engine.IDLE).any():
                 self._note_failure(si, "all lanes target quarantined cells")
@@ -388,7 +405,6 @@ class Executor:
                                    "LocalTarget")
             if stream.done():
                 return False
-            span = self.recorder.begin_issue(si, name)
             self.target.state = stream.step(self.target.spec,
                                             self.target.state)
             if self.scrubber is not None:
@@ -396,18 +412,14 @@ class Executor:
                 # scrubber can't attribute writes per-slot, so the whole
                 # table goes dirty (quarantine-only until next checkpoint)
                 self.scrubber.note_untracked()
-            self._inflight.append((None, _CarryHandle(stream), None, span))
+            self._inflight.append((None, _CarryHandle(stream), None))
         elif stream.kind == "host":
-            span = self.recorder.begin_issue(si, name)
             tok = stream.issue()
             if tok is None:
-                self.recorder.cancel_issue(span)
                 return False
-            self._inflight.append((None, tok, None, span))
+            self._inflight.append((None, tok, None))
         else:
             raise ValueError(f"unknown stream kind {stream.kind!r}")
-        self.issues += 1
-        self._trim()
         return True
 
     # -- faults --------------------------------------------------------------
@@ -461,8 +473,9 @@ class Executor:
         for f, rng in due:
             self._apply_data_fault(f, rng)
         if self.scrubber is not None:
-            rep = self.scrubber.scrub(self.target, round_idx=self._round,
-                                      baseline=baseline)
+            with TraceAnnotation(SPAN_SCRUB):
+                rep = self.scrubber.scrub(self.target, round_idx=self._round,
+                                          baseline=baseline)
             self.recorder.scrub(self._round, rep)
 
     def _apply_data_fault(self, f, rng) -> None:
@@ -531,20 +544,21 @@ class Executor:
     def checkpoint(self) -> None:
         """Drain and snapshot at a round boundary: the recovery point for
         shard loss (in-memory) and preemption resume (disk)."""
-        self._drain()
-        payload = self._ck_payload()
-        meta = {"round": self._round,
-                "seq": {str(si): int(q) for si, q in self._seq.items()},
-                "n_shards": self.target.n_shards}
-        self._last_ck = (payload, meta, len(self.history))
-        if self.scrubber is not None:
-            self.scrubber.set_checkpoint(payload["table"])
-        if self.checkpoint_dir:
-            from repro.checkpoint.disk import save_checkpoint
-            save_checkpoint(self.checkpoint_dir, self._round, payload,
-                            meta=meta)
-        self.checkpoints.append(self._round)
-        self.recorder.checkpoint(self._round)
+        with TraceAnnotation(SPAN_CHECKPOINT):
+            self._drain()
+            payload = self._ck_payload()
+            meta = {"round": self._round,
+                    "seq": {str(si): int(q) for si, q in self._seq.items()},
+                    "n_shards": self.target.n_shards}
+            self._last_ck = (payload, meta, len(self.history))
+            if self.scrubber is not None:
+                self.scrubber.set_checkpoint(payload["table"])
+            if self.checkpoint_dir:
+                from repro.checkpoint.disk import save_checkpoint
+                save_checkpoint(self.checkpoint_dir, self._round, payload,
+                                meta=meta)
+            self.checkpoints.append(self._round)
+            self.recorder.checkpoint(self._round)
 
     def _load_ck(self, payload: dict, meta: dict, hist_len: int) -> list:
         """Common restore: state, ctxs, seqs, stream cursors; returns the
@@ -569,20 +583,21 @@ class Executor:
         issued after the checkpoint were provisional)."""
         if self._last_ck is None:
             raise RuntimeError("shard loss before the first checkpoint")
-        t0 = time.perf_counter()
-        self._inflight.clear()                  # results may span the loss
-        payload, meta, hist_len = self._last_ck
-        journal = self._load_ck(payload, meta, hist_len)
-        n_surviving = self.target.n_shards - 1
-        self.target.shrink(n_surviving)
-        for si, seq in journal:
-            assert self._seq[si] == seq, (si, self._seq[si], seq)
-            self._issue(si, self.streams[si])
-        self._drain()
-        # the post-recovery state is the new baseline
-        self.checkpoint()
-        rec = Recovery(self._round, shard, self.target.n_shards,
-                       len(journal), time.perf_counter() - t0)
+        with TraceAnnotation(SPAN_RECOVER):
+            t0 = time.perf_counter()
+            self._inflight.clear()                  # results may span the loss
+            payload, meta, hist_len = self._last_ck
+            journal = self._load_ck(payload, meta, hist_len)
+            n_surviving = self.target.n_shards - 1
+            self.target.shrink(n_surviving)
+            for si, seq in journal:
+                assert self._seq[si] == seq, (si, self._seq[si], seq)
+                self._issue(si, self.streams[si])
+            self._drain()
+            # the post-recovery state is the new baseline
+            self.checkpoint()
+            rec = Recovery(self._round, shard, self.target.n_shards,
+                           len(journal), time.perf_counter() - t0)
         self.recoveries.append(rec)
         self.recorder.recovery(rec.round, shard, rec.replayed, rec.latency_s)
 

@@ -1,6 +1,6 @@
 """repro.obs acceptance suite (ISSUE 9).
 
-Two contracts, both tier-1:
+Three contracts, all tier-1:
 
   * OFF IS FREE — with BIGATOMIC_OBS unset/off the engine traces the exact
     pre-observability programs (zero new jit cache entries across a sweep)
@@ -13,11 +13,17 @@ Two contracts, both tier-1:
     strategies x {xla, pallas-interpret} engine kernels, including MCAS
     runs and distributed route-overflow lanes; and turning counters on
     never perturbs results (bit-equal to the off-mode run).
+
+  * ONE TIMELINE — the entry point and the executor write host spans, and
+    the engine round names its device scopes, in the JAX profiler's trace.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import glob
+import os
+import re
 
 import jax
 import numpy as np
@@ -284,3 +290,88 @@ def test_serving_counters_mirror_dispatch_accounting(monkeypatch):
     assert snap["serving.retired"] == 2
     assert snap["serving.decode_steps"] == 4
     assert snap["serving.dispatches"] == eng.dispatch_count == 4
+
+
+# ---------------------------------------------------------------------------
+# One timeline: spans and scopes in the profiler's trace.
+# ---------------------------------------------------------------------------
+
+def _host_spans(fn, tmp_path):
+    """Run `fn` under the JAX profiler; returns the host spans it recorded
+    as (start_ns, end_ns, name, stats) sorted by start."""
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    spans = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+              dict(ev.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith(("atomics.", "executor."))]
+    return sorted(spans)
+
+
+def _inside(inner, outer) -> bool:
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def test_apply_spans_nest_on_a_host_plane(tmp_path):
+    """One `atomics.apply` call leaves its span and its three steps, in
+    order and nested, on the profiler's host plane."""
+    spec = atomics.AtomicSpec(64, 2, "cached_me", p_max=8)
+    state = engine.init(spec)
+    ops = atomics.loads(np.arange(8, dtype=np.int32), k=2)
+    engine.apply(spec, state, ops)                    # compile outside
+
+    spans = _host_spans(
+        lambda: jax.block_until_ready(engine.apply(spec, state, ops)),
+        tmp_path)
+    assert [s[2] for s in spans] == [engine.SPAN_APPLY, engine.SPAN_VALIDATE,
+                                     engine.SPAN_TO_DEVICE,
+                                     engine.SPAN_LAUNCH]
+    outer, *steps = spans
+    assert all(_inside(s, outer) for s in steps)
+    assert all(a[1] <= b[0] for a, b in zip(steps, steps[1:]))
+
+
+def test_executor_issue_spans_hold_apply(tmp_path):
+    """An `Executor` run leaves one `executor.issue` span per issue, named
+    for its stream, each holding that batch's `atomics.apply`; the
+    round-0 checkpoint is an `executor.checkpoint` span."""
+    from repro.runtime import Executor, LocalTarget, SyntheticStream
+    n, k, width = 32, 2, 8
+    streams = [SyntheticStream(f"s{i}", seed=i, n=n, k=k, width=width,
+                               n_batches=2) for i in range(2)]
+    ex = Executor(LocalTarget(atomics.AtomicSpec(n, k, "seqlock",
+                                                 p_max=64)),
+                  streams, slots=1, oversubscription=2)
+    spans = _host_spans(ex.run, tmp_path)
+    issues = [s for s in spans if s[2] == "executor.issue"]
+    applies = [s for s in spans if s[2] == engine.SPAN_APPLY]
+    assert len(issues) == len(applies) == ex.issues == 4
+    assert sorted(s[3]["stream"] for s in issues) == ["s0", "s0", "s1",
+                                                      "s1"]
+    assert all(_inside(a, i) for a, i in zip(applies, issues))
+    assert any(s[2] == "executor.checkpoint" for s in spans)
+
+
+@pytest.mark.parametrize("kernel", ("xla", "pallas"))
+def test_round_program_names_its_scopes(kernel):
+    """The lowered round program carries every `engine.*` device scope in
+    its op metadata (both tiers sit under one `lax.cond`, so one lowering
+    holds them all) and, with the Pallas kernels, their stable names."""
+    from repro.kernels import engine_round
+    spec = atomics.AtomicSpec(64, 2, "cached_me", p_max=8)
+    ops = engine.canonicalize_ops(atomics.loads(np.arange(8, dtype=np.int32),
+                                                k=2))
+    text = engine._apply.lower(spec, engine.init(spec), ops, None, kernel,
+                               None).as_text(dialect="hlo", debug_info=True)
+    parts = {part for name in re.findall(r'op_name="([^"]*)"', text)
+             for part in name.split("/")}
+    scopes = {engine.SCOPE_PREDICATE, engine.SCOPE_SORT, engine.SCOPE_FAST,
+              engine.SCOPE_SLOW, engine.SCOPE_RESULTS, engine.SCOPE_COMMIT}
+    assert scopes <= parts, scopes - parts
+    kernels = {engine_round.FAST_KERNEL, engine_round.SLOW_KERNEL}
+    assert (kernels <= parts) == (kernel == "pallas")

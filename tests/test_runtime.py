@@ -272,7 +272,7 @@ def test_straggler_flagged_after_exactly_patience_rounds():
                                    patience=patience),
         injector=FaultInjector([Fault(round=1, kind="delay", stream=2,
                                       seconds=0.05, rounds=10)]),
-        recorder=Recorder(trace=False, clock=_TickClock()))
+        recorder=Recorder(clock=_TickClock()))
     ex.run()
     assert ex.recorder.flags, "degraded stream never flagged"
     first_round, flagged = ex.recorder.flags[0]
