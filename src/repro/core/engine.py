@@ -619,10 +619,12 @@ def _apply_impl(spec: AtomicSpec, state, ops: OpBatch, ctx: LinkCtx | None,
         # BIGATOMIC_OBS=off: None is an empty pytree, so this traces the
         # exact pre-observability program (tests/test_obs.py asserts it).
         return new_state, new_ctx, result, stats, traffic
-    eligible, taken = _engine_round().path_counts(
+    kernels = _engine_round()
+    eligible, taken = kernels.path_counts(
         spec.n, ops, fused=round_fn is not linearize)
-    telem = obs_telemetry.count_table(telem, spec.n, ops, result, stats,
-                                      eligible=eligible, taken=taken)
+    telem = obs_telemetry.count_table(
+        telem, spec.n, ops, result, stats, eligible=eligible, taken=taken,
+        windows=kernels.slow_windows(spec.n, spec.k, ops))
     return new_state, new_ctx, result, stats, traffic, telem
 
 

@@ -33,8 +33,8 @@ class _KernelLowering:
     """Mixin: lower the engine round to the fused fast/slow kernels
     (DESIGN.md §8).  The four paper layouts share the kernel — they all
     linearize against the same (engine_view, version) pair — but each owns
-    its lanes per grid step so a layout with wider cells can trade grid
-    steps for VMEM.  PLAIN/SIMPLOCK
+    its fast tier's lanes per grid step so a layout with wider cells can
+    trade grid steps for VMEM.  PLAIN/SIMPLOCK
     and external plug-ins inherit the base `lower_round` (None) and stay on
     the pure-XLA reference path."""
 

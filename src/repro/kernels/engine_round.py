@@ -18,13 +18,16 @@ round trip; this module is that path made real at the XLA/Pallas level:
               equivalent O(p) gather/compute/scatter XLA program.
 
   slow path   Contended batches sort by (slot, lane) once, then ONE Pallas
-              pass replays the sorted lanes sequentially per cell segment:
-              the window holding a cell is DMA'd into VMEM at its segment
-              start, all its ops apply in registers, and the window is
-              written back at the segment end — each dirty cell makes
-              exactly one HBM round trip instead of L gather/scatter
-              rounds.  Off-TPU the slow
-              path is `engine.linearize` itself (the pure-XLA reference).
+              pass replays the sorted lanes in order, segment by segment:
+              a segment is the run of lanes on one window (128 cells for
+              k < 128, one cell's rows above), so each window makes exactly
+              one HBM round trip instead of L gather/scatter rounds, and
+              no two segments touch the same bytes.  The trips overlap the
+              replay: the next windows' DMA-ins are in flight in a ring of
+              VMEM buffers while the current segment's ops apply in
+              registers, and a segment's write-back is waited on only when
+              its ring slot is reused.  Off-TPU the slow path is
+              `engine.linearize` itself (the pure-XLA reference).
 
   dispatch    `fast_path_ok` is one cheap duplicate-scatter check; a
               `lax.cond` picks the branch at runtime.  The predicate is
@@ -66,6 +69,14 @@ _ANY = pltpu.MemorySpace.ANY
 
 # Lanes per grid step: the fast tier keeps this many window DMAs in flight.
 DEFAULT_BLOCK = 8
+
+# The slow tier's lanes per grid step, the windows whose DMA-in it begins
+# ahead of their segments, and its ring of window buffers (a power of two
+# above _AHEAD: the slots beyond it give write-backs time to land).  Chosen
+# on a v5e (PERF.md).
+SLOW_BLOCK = 128
+_AHEAD = 8
+_RING = 16
 
 # The kernels' names in the profiler's trace and in compiler messages.
 FAST_KERNEL = "engine_fast_round"
@@ -242,8 +253,12 @@ LANES = 128
 
 # Per-lane scalars: one SMEM row of _NMETA int32 words per lane.
 _NMETA = 8
-_DPOS, _VROW, _LANE, _KIND, _LINK, _FLAGS = range(6)
+_DPOS, _VROW, _LANE, _KIND, _LINK, _FLAGS, _AHEAD1, _AHEAD2 = range(8)
 _LIVE, _SEG_START, _SEG_END = 1, 2, 4
+# Slow tier only: a segment start begins the DMA-in of the windows keyed in
+# _AHEAD1 / _AHEAD2, and a segment takes its version row over from the
+# segment before (_VCHAIN) or leaves it to the one after (_VKEEP).
+_FETCH1, _FETCH2, _VCHAIN, _VKEEP = 8, 16, 32, 64
 
 
 def _columns(k: int) -> bool:
@@ -311,20 +326,22 @@ def _pad_lanes(block: int, n: int, slot, kind, link_ver, expected, desired):
             jnp.concatenate([desired, jnp.zeros((pad, k), desired.dtype)]))
 
 
-def _lane_meta(n: int, k: int, slot, kind, link_ver, flags):
+def _lane_meta(n: int, k: int, slot, kind, link_ver, flags, ahead=None):
     """int32[p, _NMETA]: each lane's window position, version row, lane,
-    kind, link version and flags.  Dead and out-of-contract lanes (slot
-    outside [0, n)) point at cell 0 and are flagged not live, so no DMA
-    index ever leaves the table."""
+    kind, link version, flags and two window keys (`ahead`, zero if not
+    given).  Dead and out-of-contract lanes (slot outside [0, n)) point at
+    cell 0 and are flagged not live, so no DMA index ever leaves the
+    table."""
     live = (slot >= 0) & (slot < n)
     s = jnp.where(live, slot, 0)
     lane = s % LANES
     pos = s - lane if _columns(k) else s * _window_rows(k)
     zero = jnp.zeros_like(s)
+    ahead = ahead or (zero, zero)
     return jnp.stack([
         pos, s // LANES, lane, kind,
         lax.bitcast_convert_type(link_ver.astype(jnp.uint32), jnp.int32),
-        flags | jnp.where(live, _LIVE, 0), zero, zero], axis=1)
+        flags | jnp.where(live, _LIVE, 0), *ahead], axis=1)
 
 
 def _lane_results(drows, vrows, wit, info, meta, n: int, k: int, p: int):
@@ -551,45 +568,177 @@ def _fast_pallas(n: int, data, version, ctx: LinkCtx, ops: OpBatch, *,
 # The slow-path Pallas kernel: one sequential replay pass over sorted lanes.
 # ---------------------------------------------------------------------------
 
-def _slow_kernel(k: int, block: int):
-    wr = _window_rows(k)
+def _window_key(k: int, s):
+    """The slow tier's window key of cell s: its 128-cell window for k <
+    128 (the window's version row too), the cell itself for k >= 128."""
+    return s // LANES if _columns(k) else s
+
+
+def _slow_meta(n: int, k: int, slot, kind, link_ver):
+    """The slow kernel's lane scalars over lanes sorted by slot.
+
+    A segment is the run of live lanes on one window.  Sorted slots give
+    sorted keys, so each window is one segment and makes one trip in and
+    one out.  Segment s's start lane begins the DMA-in of segments
+    [G(s-1), G(s)), G(s) = min(S, s + _AHEAD, 2s + 2): two a segment until
+    _AHEAD windows are in flight, then one.  For k >= 128 a version row
+    spans 128 segments; consecutive segments on one row pass it along in
+    VMEM (_VCHAIN / _VKEEP), so that only the row's last segment writes
+    it back.  Flags count live lanes only: dead and out-of-range lanes
+    never split or end a segment."""
+    live = (slot >= 0) & (slot < n)
+    s = jnp.where(live, slot, 0)
+    key, vrow = _window_key(k, s), s // LANES
+    no = jnp.zeros((1,), bool)
+
+    def prev(x):
+        return jnp.concatenate([x[:1], x[:-1]])
+
+    def succ(x):
+        return jnp.concatenate([x[1:], x[-1:]])
+
+    live_prev = jnp.concatenate([no, live[:-1]])
+    live_next = jnp.concatenate([live[1:], no])
+    start = live & ~(live_prev & (prev(key) == key))
+    end = live & ~(live_next & (succ(key) == key))
+    chain = start & live_prev & (prev(vrow) == vrow)
+    keep = end & live_next & (succ(vrow) == vrow)
+
+    seg = jnp.cumsum(start.astype(jnp.int32)) - 1
+    n_seg = seg[-1] + 1
+    pp = slot.shape[0]
+    seg_key = jnp.zeros((pp,), jnp.int32).at[
+        jnp.where(start, seg, pp)].set(key, mode="drop")
+
+    def issued(x):                       # G(x): fetches begun through x
+        return jnp.minimum(n_seg, jnp.minimum(x + _AHEAD, 2 * x + 2))
+
+    lo = jnp.where(seg > 0, issued(seg - 1), 0)
+    hi = issued(seg)
+    ahead = (seg_key[jnp.clip(lo, 0, pp - 1)],
+             seg_key[jnp.clip(lo + 1, 0, pp - 1)])
+
+    def bit(mask, b):
+        return jnp.where(mask, b, 0)
+
+    flags = (bit(start, _SEG_START) | bit(end, _SEG_END)
+             | bit(start & (hi > lo), _FETCH1)
+             | bit(start & (hi > lo + 1), _FETCH2)
+             | bit(chain, _VCHAIN) | bit(keep, _VKEEP))
+    return _lane_meta(n, k, slot, kind, link_ver, flags, ahead)
+
+
+def slow_windows(n: int, k: int, ops: OpBatch) -> jax.Array:
+    """The window round trips the slow kernel makes for `ops`: its
+    distinct live window keys (`repro.obs` counts it on slow batches)."""
+    live = (ops.kind != IDLE) & (ops.slot >= 0) & (ops.slot < n)
+    n_keys = _window_key(k, n - 1) + 1
+    key = jnp.where(live, _window_key(k, ops.slot), n_keys)
+    hit = jnp.zeros((n_keys + 1,), jnp.int32).at[key].set(1, mode="drop")
+    return jnp.sum(hit[:n_keys])
+
+
+def _slow_kernel(k: int):
+    wr, ring_mask = _window_rows(k), _RING - 1
 
     def kernel(meta_ref, exp_ref, des_ref, data_in, ver_in, out_data,
-               out_ver, wit_ref, info_ref, win, vrow, sem):
+               out_ver, wit_ref, info_ref, ring, vring, sem_in, vsem_in,
+               sem_out, vsem_out, count, pend):
         del data_in, ver_in                 # aliased to out_data / out_ver
+
+        def hbm(pos, vrow):
+            """The HBM window and version row at a lane's (_DPOS, _VROW)."""
+            return _window(out_data, pos, k), out_ver.at[pl.ds(vrow, 1)]
+
+        def keyed(key):
+            """The HBM window and version row of a window key."""
+            if _columns(k):
+                return hbm(key * LANES, key)
+            return hbm(key * wr, key // LANES)
+
+        def trip_in(win, r):
+            dwin, vwin = win
+            return (pltpu.make_async_copy(dwin, ring.at[r], sem_in.at[r]),
+                    pltpu.make_async_copy(vwin, vring.at[r], vsem_in.at[r]))
+
+        def trip_out(win, r):
+            dwin, vwin = win
+            return (pltpu.make_async_copy(ring.at[r], dwin, sem_out.at[r]),
+                    pltpu.make_async_copy(vring.at[r], vwin, vsem_out.at[r]))
+
+        def drain(r):
+            """Wait out ring slot r's write-backs still in flight."""
+            p = pend[r]
+            for b, cp in zip((1, 2), trip_out(hbm(0, 0), r)):
+                @pl.when((p & b) != 0)
+                def _():
+                    cp.wait()
+            pend[r] = 0
+
+        # count[0]: segments begun; count[1]: windows whose DMA-in began.
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            count[0] = 0
+            count[1] = 0
+            for r in range(_RING):
+                pend[r] = 0
 
         def lane(j, _):
             def m(f):
                 return meta_ref[j, f]
 
             flags = m(_FLAGS)
-            dwin = _window(out_data, m(_DPOS), k)
-            vwin = out_ver.at[pl.ds(m(_VROW), 1)]
             r = pl.ds(j * wr, wr)
 
             @pl.when((flags & _LIVE) != 0)
             def _():
-                # Segment start: the cell's window makes its ONE trip in.
                 @pl.when((flags & _SEG_START) != 0)
                 def _():
-                    _copy(dwin, win, sem)
-                    _copy(vwin, vrow, sem)
+                    # Keep the next windows in flight, each into the ring
+                    # slot whose write-back (a finished segment's) is done.
+                    for b, f in ((_FETCH1, _AHEAD1), (_FETCH2, _AHEAD2)):
+                        @pl.when((flags & b) != 0)
+                        def _():
+                            t = count[1]
+                            drain(t & ring_mask)
+                            for cp in trip_in(keyed(m(f)), t & ring_mask):
+                                cp.start()
+                            count[1] = t + 1
 
+                    # This segment's window was begun at least one segment
+                    # ago (the first: just now).
+                    slot = count[0] & ring_mask
+                    count[0] = count[0] + 1
+                    for cp in trip_in(hbm(0, 0), slot):
+                        cp.wait()
+
+                    @pl.when((flags & _VCHAIN) != 0)
+                    def _():
+                        vring[slot] = vring[(slot - 1) & ring_mask]
+
+                slot = (count[0] - 1) & ring_mask
                 dmask, vmask = _masks(k, m(_LANE))
-                cv, vv = win[...], vrow[...]
+                cv, vv = ring[slot], vring[slot]
                 okw, succ, ver = _judge(cv, vv, exp_ref[r, :], m(_KIND),
                                         m(_LINK), dmask, vmask)
                 wit_ref[r, :] = cv
                 info_ref[pl.ds(j, 1), :] = _info(ver, succ)
-                win[...], vrow[...] = _commit(cv, vv, des_ref[r, :], okw,
-                                              dmask, vmask)
+                ring[slot], vring[slot] = _commit(cv, vv, des_ref[r, :], okw,
+                                                  dmask, vmask)
 
-                # Segment end: write the (possibly dirty) window back.  A
-                # later segment on a cell of the same window reads it again.
+                # Segment end: start the write-back and go on; the slot's
+                # next DMA-in, or the kernel's end, waits it out.
                 @pl.when((flags & _SEG_END) != 0)
                 def _():
-                    _copy(win, dwin, sem)
-                    _copy(vrow, vwin, sem)
+                    back, vback = trip_out(hbm(m(_DPOS), m(_VROW)), slot)
+                    back.start()
+                    keep = (flags & _VKEEP) != 0
+
+                    @pl.when(~keep)
+                    def _():
+                        vback.start()
+
+                    pend[slot] = jnp.where(keep, 1, 3)
 
             @pl.when((flags & _LIVE) == 0)
             def _():
@@ -598,53 +747,68 @@ def _slow_kernel(k: int, block: int):
 
             return 0
 
-        lax.fori_loop(0, block, lane, 0)
+        lax.fori_loop(0, SLOW_BLOCK, lane, 0)
+
+        @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
+        def _():
+            for r in range(_RING):
+                drain(r)
 
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def slow_round_pallas(data, version, s_slot, s_kind, s_link_ver, s_expected,
-                      s_desired, *, block: int = DEFAULT_BLOCK,
-                      interpret: bool = False):
+                      s_desired, *, interpret: bool = False):
     """One fused sequential-replay pass over lanes SORTED by (slot, lane).
 
     Fuses the per-segment arbitration and all L combining rounds of
-    `engine.linearize._general` into one kernel: a segment's cell window is
-    DMA'd in once, every op of the segment applies in registers (full
-    LOAD/STORE/CAS/LL/SC/VALIDATE semantics), and the window is written back
-    once -- replacing the gather -> check -> scatter `while_loop` round
-    trips.  The per-lane DMAs here are deliberately serialized: the replay
-    is sequential by definition (lane j+1 may read what lane j wrote), so
-    only the blocked op tiles pipeline across grid steps.
+    `engine.linearize._general` into one kernel.  The lanes fall into
+    window segments (`_slow_meta`): the runs of lanes on one 128-cell
+    window (k < 128) or one cell (k >= 128).  Each window makes one trip
+    into VMEM and one back, and every op of its segment applies to it in
+    registers in lane order (full LOAD/STORE/CAS/LL/SC/VALIDATE semantics)
+    -- replacing the gather -> check -> scatter `while_loop` rounds.
+
+    The replay is sequential (lane j+1 may read what lane j wrote), but
+    only within a window: no two segments touch the same HBM bytes.  So
+    the trips overlap the replay: a ring of _RING window buffers in VMEM,
+    the DMA-in of the next _AHEAD windows begun before their segments
+    (keys carried by the segment starts' lane scalars), and write-backs
+    started at a segment's end and waited on only when the ring slot is
+    reused, or at the kernel's end.  Ring, semaphores and counters persist
+    across grid steps, so a segment may span many lane tiles of
+    SLOW_BLOCK lanes.
 
     Returns (data', version', val_pt[p, k], ver_pt[p], success[p]) in the
     SORTED lane order."""
     n, k = data.shape
     p, wr = s_slot.shape[0], _window_rows(k)
-    # Padding lanes are dead (slot n) and sort AFTER every live lane, so
-    # they never split a real segment.
+    # Padding lanes are dead (slot n): they sort after every live lane and
+    # never split or end a segment.
     s_slot, s_kind, s_link_ver, s_expected, s_desired = _pad_lanes(
-        block, n, s_slot, s_kind, s_link_ver, s_expected, s_desired)
-    new_cell = s_slot[1:] != s_slot[:-1]
-    edge = jnp.ones((1,), bool)
-    flags = (jnp.where(jnp.concatenate([edge, new_cell]), _SEG_START, 0)
-             | jnp.where(jnp.concatenate([new_cell, edge]), _SEG_END, 0))
-    meta = _lane_meta(n, k, s_slot, s_kind, s_link_ver, flags)
+        SLOW_BLOCK, n, s_slot, s_kind, s_link_ver, s_expected, s_desired)
+    meta = _slow_meta(n, k, s_slot, s_kind, s_link_ver)
     drows, vrows = _table_view(data, version)
     scratch = [
-        pltpu.VMEM((wr, LANES), data.dtype),
-        pltpu.VMEM((1, LANES), jnp.uint32),
-        pltpu.SemaphoreType.DMA(()),
+        pltpu.VMEM((_RING, wr, LANES), data.dtype),
+        pltpu.VMEM((_RING, 1, LANES), jnp.uint32),
+        pltpu.SemaphoreType.DMA((_RING,)),
+        pltpu.SemaphoreType.DMA((_RING,)),
+        pltpu.SemaphoreType.DMA((_RING,)),
+        pltpu.SemaphoreType.DMA((_RING,)),
+        pltpu.SMEM((2,), jnp.int32),
+        pltpu.SMEM((_RING,), jnp.int32),
     ]
-    out = _round_call(_slow_kernel(k, block), SLOW_KERNEL, k, block, meta,
-                      _lane_rows(s_expected), _lane_rows(s_desired), drows,
-                      vrows, scratch, interpret)
+    out = _round_call(_slow_kernel(k), SLOW_KERNEL, k,
+                      SLOW_BLOCK, meta, _lane_rows(s_expected),
+                      _lane_rows(s_desired), drows, vrows, scratch,
+                      interpret)
     return _lane_results(*out, meta, n, k, p)
 
 
 def _slow_pallas(n: int, data, version, ctx: LinkCtx, ops: OpBatch, *,
-                 block: int, interpret: bool):
+                 interpret: bool):
     """Sort once, replay in one kernel pass, then rebuild ctx/result/stats
     exactly as `linearize` defines them (two cheap scans; no while_loop)."""
     kind = ops.kind
@@ -661,7 +825,7 @@ def _slow_pallas(n: int, data, version, ctx: LinkCtx, ops: OpBatch, *,
     with jax.named_scope(engine.SCOPE_SLOW):
         new_data, new_version, val_s, verpt_s, succ_i = slow_round_pallas(
             data, version, s_slot, s_kind, s_link_ver, s_expected, s_desired,
-            block=block, interpret=interpret)
+            interpret=interpret)
 
     with jax.named_scope(engine.SCOPE_RESULTS):
         s_success = succ_i != 0
@@ -712,8 +876,7 @@ def make_round(n: int, k: int, *, mode: str | None = None,
         if r_mode == "pallas":
             fast = functools.partial(_fast_pallas, n, block=block,
                                      interpret=interpret)
-            slow = functools.partial(_slow_pallas, n, block=block,
-                                     interpret=interpret)
+            slow = functools.partial(_slow_pallas, n, interpret=interpret)
         else:
             fast = functools.partial(_fast_xla, n)
 

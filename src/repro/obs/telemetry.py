@@ -85,6 +85,10 @@ class Telemetry(NamedTuple):
       rounds          sum of ApplyStats.rounds (serialization rounds L)
       slow_rounds     rounds spent on batches NOT taken by the fast path
                       (the slow-path replay cost)
+      slow_windows    window round trips of the batches NOT taken by the
+                      fast path: their distinct live windows
+                      (`engine_round.slow_windows`), one DMA in and one
+                      out each in the slow kernel
       cas_fail        active CAS lanes that failed
       sc_fail         active SC lanes that failed (stale link or lost race)
       raced_loads     loads whose cell saw a same-batch write
@@ -109,6 +113,7 @@ class Telemetry(NamedTuple):
     fast_taken: jax.Array
     rounds: jax.Array
     slow_rounds: jax.Array
+    slow_windows: jax.Array
     cas_fail: jax.Array
     sc_fail: jax.Array
     raced_loads: jax.Array
@@ -129,7 +134,7 @@ def init_telemetry() -> Telemetry:
     return Telemetry(
         batches=z, ops_kind=jnp.zeros((N_KINDS,), jnp.int32),
         fast_eligible=z, fast_taken=z, rounds=z, slow_rounds=z,
-        cas_fail=z, sc_fail=z, raced_loads=z, dirty_cells=z,
+        slow_windows=z, cas_fail=z, sc_fail=z, raced_loads=z, dirty_cells=z,
         contention_hist=jnp.zeros((N_HIST,), jnp.int32),
         torn_retries=z, mcas_commits=z, mcas_aborts=z, mcas_rounds=z,
         mcas_backoff=z, route_overflow=z, collective_rounds=z,
@@ -148,10 +153,12 @@ def contention_bucket(c: jax.Array) -> jax.Array:
 
 
 def count_table(t: Telemetry, n: int, ops, result, stats, *,
-                eligible: jax.Array, taken: jax.Array) -> Telemetry:
+                eligible: jax.Array, taken: jax.Array,
+                windows: jax.Array) -> Telemetry:
     """Accumulate one `engine.apply` batch from masks the round already
     materialized (ops, per-lane success, ApplyStats, and the fast-path
-    predicate / taken branch from `engine_round.path_counts`)."""
+    predicate / taken branch from `engine_round.path_counts`), and the
+    batch's window count `engine_round.slow_windows`."""
     kind, slot = ops.kind, ops.slot
     success = result.success
     one = jnp.int32(1)
@@ -173,6 +180,7 @@ def count_table(t: Telemetry, n: int, ops, result, stats, *,
         fast_taken=t.fast_taken + taken,
         rounds=t.rounds + stats.rounds,
         slow_rounds=t.slow_rounds + (1 - taken) * stats.rounds,
+        slow_windows=t.slow_windows + (1 - taken) * windows,
         cas_fail=t.cas_fail + jnp.sum(
             (active & (kind == 2) & ~success).astype(jnp.int32)),
         sc_fail=t.sc_fail + jnp.sum(
@@ -287,6 +295,7 @@ def snapshot() -> dict:
     out["engine.fast.taken"] = int(t.fast_taken)
     out["engine.rounds.total"] = int(t.rounds)
     out["engine.rounds.slow"] = int(t.slow_rounds)
+    out["engine.slow.windows"] = int(t.slow_windows)
     out["engine.fail.cas"] = int(t.cas_fail)
     out["engine.fail.sc"] = int(t.sc_fail)
     out["engine.loads.raced"] = int(t.raced_loads)

@@ -391,6 +391,15 @@ def _np_stats_sorted(n: int, kind: np.ndarray, slot: np.ndarray,
     return rounds, raced, dirty
 
 
+def _np_windows(n, ops):
+    """Distinct live windows of a batch: 128-cell windows for k < 128,
+    cells for k >= 128 (the slow kernel's window round trips)."""
+    kind, slot = np.asarray(ops.kind), np.asarray(ops.slot)
+    live = (kind != engine.IDLE) & (slot >= 0) & (slot < n)
+    per = 128 if np.asarray(ops.expected).shape[1] < 128 else 1
+    return len(np.unique(slot[live] // per))
+
+
 class TelemetryOracle:
     """Recount the `repro.obs` in-graph counters from the oracle's own
     inputs: op batches, delivered results, MCAS results and distributed
@@ -427,6 +436,8 @@ class TelemetryOracle:
         rounds, raced, dirty = _np_stats_sorted(self.n, kind, slot, success)
         self._add("engine.rounds.total", rounds)
         self._add("engine.rounds.slow", 0 if taken else rounds)
+        self._add("engine.slow.windows",
+                  0 if taken else _np_windows(self.n, ops))
         self._add("engine.fail.cas",
                   np.sum(active & (kind == engine.CAS) & ~success))
         self._add("engine.fail.sc",

@@ -121,6 +121,98 @@ def test_round_matches_linearize_table_views(n, k, spectrum):
         data, ver = ref[0], ref[1]
 
 
+def _zipf_slots(rng, n, p, theta=0.99):
+    """Zipf(theta) ranks over n cells, spread by an odd multiply."""
+    w = np.arange(1, n + 1, dtype=np.float64) ** -theta
+    ranks = rng.choice(n, p, p=w / w.sum())
+    return ((ranks * 2654435761 + 17) % n).astype(np.int32)
+
+
+def _slow_case(case, rng):
+    """(n, k, slots) of a colliding batch that exercises one part of the
+    slow kernel's window pipeline (`engine_round._slow_meta`)."""
+    ring, ahead = engine_round._RING, engine_round._AHEAD
+    if case == "one_window":
+        # distinct written cells of one 128-cell window, adjacent once
+        # sorted, with a second window on either side
+        return 512, 4, np.asarray([130, 131, 131, 135, 200, 255, 129, 140,
+                                   3, 300, 131, 254], np.int32)
+    if case == "hot_cell":
+        # one cell with more lanes than a lane tile and the lookahead, and
+        # more windows than ring slots, so segments span grid steps and
+        # the ring wraps
+        hot = max(engine_round.SLOW_BLOCK, ahead) + 5
+        windows = 2 * ring + 3
+        n = 128 * windows
+        others = rng.integers(0, 128, windows) + 128 * np.arange(windows)
+        slots = np.concatenate([np.full(hot, 128 * (windows // 2) + 7),
+                                others, others[::3]])
+        return n, 2, rng.permutation(slots).astype(np.int32)
+    if case == "ragged_n":
+        # n = 300: the last window holds live cells 256..299 beside dead
+        # (slot n) and out-of-range lanes, which must not split its segment
+        n = 300
+        slots = np.concatenate([rng.integers(250, n, 20), [n, n + 3, 299],
+                                rng.integers(0, n, 16), [-1, 298, 298]])
+        return n, 4, rng.permutation(slots).astype(np.int32)
+    if case in ("rows_k128", "rows_k130"):
+        # one cell per window; neighbouring cells share a version row
+        k = 128 if case == "rows_k128" else 130
+        slots = np.concatenate([np.arange(0, 20), np.arange(5, 12),
+                                [130, 131, 131, 259, 140]])
+        return 260, k, rng.permutation(slots).astype(np.int32)
+    assert case == "zipf"
+    return 4096, 2, _zipf_slots(rng, 4096, 256)
+
+
+@pytest.mark.parametrize("case", ["one_window", "hot_cell", "ragged_n",
+                                  "rows_k128", "rows_k130", "zipf"])
+def test_slow_kernel_window_segments(case):
+    """The slow kernel against `linearize` on batches that stress its
+    window segments: a segment per window (not per cell), lookahead DMAs
+    through a ring that persists across lane tiles, live-only flags, and
+    version rows handed between row-window segments."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    n, k, slots = _slow_case(case, rng)
+    p = len(slots)
+    data, ver = make_table(n, k, seed=p)
+    kinds = rng.choice(np.asarray([atomics.LOAD, atomics.STORE, atomics.CAS,
+                                   atomics.LL, atomics.SC, atomics.VALIDATE,
+                                   atomics.IDLE]), p,
+                       p=[.3, .3, .1, .1, .1, .05, .05]).astype(np.int32)
+    kinds[:2] = atomics.STORE           # a write: never the fast tier
+    # Out-of-range active lanes are out of contract: the kernel makes them
+    # failed no-ops, so `linearize` sees them idle.
+    oor = (slots < 0) | (slots >= n)
+    kinds[oor] = np.where(np.arange(p)[oor] % 2, atomics.LOAD, atomics.STORE)
+    ops, ctx = make_batch(rng, n, k, p, "none" if p <= n else "low",
+                          data=data, ver=ver)
+    ops = ops._replace(kind=jnp.asarray(kinds), slot=jnp.asarray(slots))
+    in_contract = ops._replace(
+        kind=jnp.asarray(np.where(oor, atomics.IDLE, kinds)))
+    assert not bool(engine_round.fast_path_ok(n, ops))
+    round_fn = engine_round.make_round(n, k, mode="pallas", interpret=True)
+    for trial in range(2):
+        ref = engine.linearize(data, ver, ctx, in_contract)
+        out = round_fn(data, ver, ctx, ops)
+        if oor.any():
+            res = out[3]
+            assert not np.asarray(res.success)[oor].any()
+            np.testing.assert_array_equal(np.asarray(res.value)[oor], 0)
+            lanes = lambda a: np.asarray(a)[~oor]      # noqa: E731
+            ref = (*ref[:3], jax.tree.map(lanes, ref[3]))
+            out = (*out[:3], jax.tree.map(lanes, out[3]))
+        assert_rounds_equal(ref, out, f"{case}/trial{trial}")
+        data, ver, ctx = ref[0], ref[1], ref[2]
+
+    # The counter's definition is the kernel's segment count.
+    meta = engine_round._slow_meta(
+        n, k, jnp.sort(jnp.where(ops.kind != atomics.IDLE, ops.slot, n)),
+        ops.kind, jnp.ones(p, jnp.uint32))
+    starts = np.sum((np.asarray(meta[:, 5]) & engine_round._SEG_START) != 0)
+    assert starts == int(engine_round.slow_windows(n, k, ops))
+
+
 @pytest.mark.parametrize("strategy", STRATEGIES)
 @pytest.mark.parametrize("spectrum", SPECTRA)
 def test_apply_matches_oracle_under_kernel_round(strategy, spectrum):

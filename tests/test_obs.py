@@ -133,6 +133,33 @@ def test_engine_counters_match_oracle(monkeypatch, strategy, kernel):
                          for name in want if got[name] != want[name]}
 
 
+@pytest.mark.parametrize("kernel", ("xla", "pallas"))
+def test_slow_windows_count_distinct_live_windows(monkeypatch, kernel):
+    """`engine.slow.windows` is the slow tier's window round trips: the
+    distinct live 128-cell windows of the batches it ran, and 0 for
+    collision-free and read-only batches (the fast tier runs those)."""
+    monkeypatch.setenv("BIGATOMIC_OBS", "counters")
+    monkeypatch.setenv("BIGATOMIC_ENGINE_KERNEL", kernel)
+    obs.reset()
+    n, k = 600, 2
+    spec = atomics.AtomicSpec(n, k, "cached_me", p_max=8)
+    state = engine.init(spec)
+    S, L, I = atomics.STORE, atomics.LOAD, atomics.IDLE
+    # Windows 0, 1 and 4; an idle lane and out-of-range lanes make none.
+    slow = atomics.make_ops([S, L, S, S, L, S, I, S],
+                            [3, 3, 127, 130, 520, 599, 200, n + 1], k=k)
+    distinct = atomics.make_ops([S, S, L, S, S, L, S, S],
+                                [0, 1, 2, 3, 200, 300, 400, 599], k=k)
+    read_only = atomics.make_ops([L] * 8, [5, 5, 5, 140, 140, 1, 2, 3], k=k)
+    for ops, windows in ((slow, 3), (distinct, 0), (read_only, 0),
+                         (slow, 3)):
+        before = obs.snapshot()["engine.slow.windows"]
+        state, *_ = engine.apply(spec, state, ops)
+        assert obs.snapshot()["engine.slow.windows"] - before == windows
+    snap = obs.snapshot()
+    assert snap["engine.batches"] == 4 and snap["engine.fast.taken"] == 2
+
+
 def test_counters_do_not_perturb_results(monkeypatch):
     """The counters program must compute the exact same table/results as
     the off program — counters observe, never steer."""
