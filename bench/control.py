@@ -32,7 +32,7 @@ def run_control(plan: dict, seed: int, seconds: float, weaken: str,
     mod = run.system(plan["config"]["system"])
     out = run.run_cell(
         plan, seed, seconds, False, devices,
-        make_cell=lambda c, t, s: mod.Control(c, t, s, WEAKEN[weaken]))
+        make_cell=lambda c, t, s, d: mod.Control(c, t, s, d, WEAKEN[weaken]))
     return {"weaken": weaken, **out}
 
 

@@ -163,7 +163,8 @@ def run_cell(plan: dict, seed: int, seconds: float, trace: bool, devices,
 
     compiles = _Compiles()
     mod = system(plan["config"]["system"])
-    cell = (make_cell or mod.Cell)(plan["config"], plan["traffic"], seed)
+    cell = (make_cell or mod.Cell)(plan["config"], plan["traffic"], seed,
+                                   devices)
     history = list(cell.setup_history)
     results = list(cell.setup_results)
     pool = range(cell.window_first, len(cell.ops))

@@ -9,12 +9,22 @@ from bench import gen, reference
 from repro import atomics
 
 CODES = {"LOAD": atomics.LOAD, "STORE": atomics.STORE, "IDLE": atomics.IDLE}
+WRITES = ("STORE",)
+TINY = {"n_cells": 4096, "lanes": 64}
 
 
 def entry(spec, state, ops):
     """The call the window times, as a caller that threads its state makes
     it: (state', ctx', ApplyResult, ApplyStats, Traffic)."""
     return atomics.apply(spec, state, ops, donate=True)
+
+
+def change_record(state, record: int, field: str):
+    """`state` with one record changed: its first word ("data") or its
+    version ("version")."""
+    if field == "data":
+        return state._replace(data=state.data.at[record, 0].add(1))
+    return state._replace(version=state.version.at[record].set(2))
 
 
 def _untouched_mismatches(spec, seed: int, state, touched) -> int:
@@ -43,13 +53,14 @@ def _build(spec, seed: int):
 
 
 class Cell:
-    """config: n_cells, k_words, lanes, strategy."""
+    """config: n_cells, k_words, lanes, strategy.  The table lives on the
+    first of `devices`."""
 
     codes = CODES
 
-    def __init__(self, config: dict, traffic: dict, seed: int):
+    def __init__(self, config: dict, traffic: dict, seed: int, devices):
         n, k, p = config["n_cells"], config["k_words"], config["lanes"]
-        self.seed, self.k = seed, k
+        self.seed, self.k, self.devices = seed, k, devices
         self.spec = atomics.AtomicSpec(n, k, config["strategy"], p_max=p)
         pool = gen.draw(traffic, space=n, lanes=p, width=k, seed=seed,
                         codes=CODES)
@@ -64,7 +75,8 @@ class Cell:
         self.setup_results: list = []
 
     def _build(self):
-        return jax.block_until_ready(_build(self.spec, self.seed))
+        with jax.default_device(self.devices[0]):
+            return jax.block_until_ready(_build(self.spec, self.seed))
 
     def lanes(self, b: int) -> int:
         return len(self.ops[b].kind)
@@ -126,10 +138,10 @@ class Cell:
 class Control(Cell):
     """The reference, weakened, in the program's place."""
 
-    def __init__(self, config, traffic, seed, weaken: dict):
+    def __init__(self, config, traffic, seed, devices, weaken: dict):
         self._weaken = weaken
         self._ref = None
-        super().__init__(config, traffic, seed)
+        super().__init__(config, traffic, seed, devices)
 
     def _build(self):
         return None

@@ -1,5 +1,6 @@
 """The harness end to end on the CPU: no result without a TPU, the plan
-of every cell, and `correct` against planted faults and the controls."""
+of every cell, and `correct` against planted faults and the controls, each
+cell on as many CPU devices as it asks for chips (`cells.py`)."""
 
 import os
 import re
@@ -7,14 +8,12 @@ import shutil
 import subprocess
 import sys
 
-import jax
 import numpy as np
 import pytest
 
-from bench import control, run
-from bench.systems import table as table_sys
+from bench import run
 
-from .conftest import benchmark, cells, tiny_plan
+from .cells import benchmark, cases, cells, outcome, tiny_plan
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -60,12 +59,22 @@ def test_every_cell_resolves_by_name():
         assert "setup_s" in e2e and len(e2e) >= 2 and plan["per_layer"]
         assert all(m["moves"] in e2e for m in plan["per_layer"])
         mod = run.system(plan["config"]["system"])
+        assert all(hasattr(mod, name) for name in
+                   ("CODES", "WRITES", "TINY", "entry", "Cell", "Control"))
         assert set(plan["traffic"]["mix"]) <= set(mod.CODES)
 
 
+CASES = [(c, case) for c in cells() for case in cases(c)]
+
+
+def _of(kind: str) -> list:
+    return [(c, case.partition(":")[2]) for c, case in CASES
+            if case.startswith(kind + ":")]
+
+
 @pytest.mark.parametrize("cell", cells())
-def test_sound_run_is_correct(cell, cpu):
-    out = run.run_cell(tiny_plan(cell), 2 ** 33 + 5, 0.2, False, cpu)
+def test_sound_run_is_correct(cell):
+    out = outcome(cell, "sound")
     assert out["correct"], out["checks"]
     assert out["attempted"] > 0 and out["failed"] == 0
     assert out["window_compiles"] == 0
@@ -73,87 +82,45 @@ def test_sound_run_is_correct(cell, cpu):
     plan = tiny_plan(cell)
     assert set(out["metrics"]) == {m["name"] for m in plan["end_to_end"]}
     assert all(m["value"] > 0 for m in out["metrics"].values())
-
-
-def _faults(module):
-    """Faults planted under the timed entry point: name -> wrapper."""
-    idle = module.CODES["IDLE"]
-
-    def unchanged(entry):
-        def f(spec, state, ops):
-            return (state, *entry(spec, state, ops)[1:])
-        return f
-
-    def half_left_out(entry):
-        def f(spec, state, ops):
-            kind = np.asarray(ops.kind).copy()
-            kind[len(kind) // 2:] = idle
-            return entry(spec, state, ops._replace(kind=kind))
-        return f
-
-    def answer_altered(entry):
-        def f(spec, state, ops):
-            out = list(entry(spec, state, ops))
-            i = next(i for i, x in enumerate(out) if hasattr(x, "success"))
-            value = out[i].value.at[0, 0].set(out[i].value[0, 0] ^ 1)
-            out[i] = out[i]._replace(value=value)
-            return tuple(out)
-        return f
-
-    return {"unchanged": unchanged, "half_left_out": half_left_out,
-            "answer_altered": answer_altered}
-
-
-FAULT_CASES = [(c, f) for c in cells() for f in
-               ("unchanged", "half_left_out", "answer_altered")
-               if not (f == "unchanged" and "ycsb-c" in c)]
-
-
-@pytest.mark.parametrize("cell,fault", FAULT_CASES)
-def test_planted_fault_is_not_correct(cell, fault, cpu, monkeypatch):
-    plan = tiny_plan(cell)
-    monkeypatch.setattr(table_sys, "entry",
-                        _faults(table_sys)[fault](table_sys.entry))
-    out = run.run_cell(plan, 2 ** 33 + 6, 0.2, False, cpu)
-    assert not out["correct"], out["checks"]
+    assert out["device"]["count"] == plan["chips"]
 
 
 @pytest.mark.parametrize("cell", cells())
-@pytest.mark.parametrize("field", ["data", "version"])
-def test_untouched_cell_changed_is_not_correct(cell, field, cpu,
-                                               monkeypatch):
-    """A step that also changes a cell no batch names is caught by the
-    whole-table count, and by nothing else."""
-    plan, seed = tiny_plan(cell), 2 ** 33 + 8
-    probe = table_sys.Cell(plan["config"], plan["traffic"], seed)
-    named = set(probe.touched(range(len(probe.ops))).tolist())
-    victim = min(set(range(probe.spec.n)) - named)
-    entry = table_sys.entry
+def test_traced_run_reads_its_metrics(cell):
+    """A traced run is as correct, and reads per-layer metrics of its own
+    cell only, at least one of them (on the CPU a reader of the chip's
+    kernels finds nothing to read)."""
+    out = outcome(cell, "traced")
+    assert out["correct"], out["checks"]
+    assert out["window_compiles"] == 0
+    names = {m["name"] for m in tiny_plan(cell)["per_layer"]}
+    assert out["metrics"] and set(out["metrics"]) <= names
+    assert all(np.isfinite(m["value"]) and m["value"] >= 0
+               for m in out["metrics"].values())
+    assert 0 < out["device"]["busy_s"] <= out["device"]["window_s"]
+    assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
 
-    def changes_one_more(spec, state, ops):
-        state, *rest = entry(spec, state, ops)
-        if field == "data":
-            state = state._replace(
-                data=state.data.at[victim, 0].add(1))
-        else:
-            state = state._replace(
-                version=state.version.at[victim].set(2))
-        return (state, *rest)
 
-    monkeypatch.setattr(table_sys, "entry", changes_one_more)
-    out = run.run_cell(plan, seed, 0.2, False, cpu)
+@pytest.mark.parametrize("cell,fault", _of("fault"))
+def test_planted_fault_is_not_correct(cell, fault):
+    out = outcome(cell, f"fault:{fault}")
+    assert not out["correct"], out["checks"]
+
+
+@pytest.mark.parametrize("cell,field", _of("untouched"))
+def test_untouched_cell_changed_is_not_correct(cell, field):
+    """A step that also changes a record no batch names is caught by the
+    whole-table count (the check named `untouched...`), and by nothing
+    else."""
+    out = outcome(cell, f"untouched:{field}")
     assert not out["correct"]
     checks = {k: c["value"] for k, c in out["checks"].items()}
-    assert checks == {"op_mismatches": 0, "cell_mismatches": 0,
-                      "untouched_mismatches": 1}, checks
+    assert {k: v for k, v in checks.items() if v} == {
+        k: 1 for k in checks if k.startswith("untouched")}, checks
 
 
-CONTROL_CASES = [(c, w) for c in cells() for w in ("half_words", "snapshot")
-                 if not (w == "snapshot" and "ycsb-c" in c)]
-
-
-@pytest.mark.parametrize("cell,weaken", CONTROL_CASES)
-def test_control_is_not_correct(cell, weaken, cpu):
-    out = control.run_control(tiny_plan(cell), 2 ** 33 + 7, 0.2, weaken, cpu)
+@pytest.mark.parametrize("cell,weaken", _of("control"))
+def test_control_is_not_correct(cell, weaken):
+    out = outcome(cell, f"control:{weaken}")
     assert not out["correct"], out["checks"]
     assert out["checks"]["op_mismatches"]["value"] > 0
