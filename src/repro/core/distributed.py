@@ -15,17 +15,27 @@ executes a globally linearizable batch over the FULL op schema:
                `LinkCtx`).  Lanes beyond capacity are NOT silently dropped:
                they surface in the returned per-lane `overflow` mask with
                `success=False` and leave the table untouched.
-  2. apply   — every shard runs the LOCAL v2 linearization
-               (`engine.linearize` over `StrategyImpl.engine_view`/`commit`,
-               resolved through the strategy registry) on the ops it owns,
-               so all registered layouts — built-in or test-registered —
-               run sharded unchanged.  Linearization order is
-               (owner, src device, lane) — a fixed total order, so the
-               result equals a global sequential application in that order
-               (`linearization_order` emits it for the oracle harness).
+  2. apply   — every shard runs the engine's round on the ops it owns:
+               `engine.round_for` (the tier predicate and the strategy's
+               fast and slow kernels, or `engine.linearize` where the
+               layout lowers none) and the strategy's `commit`, resolved
+               through the registry, so all registered layouts — built-in
+               or test-registered — run sharded unchanged.  Linearization
+               order is (owner, src device, lane) — a fixed total order,
+               so the result equals a global sequential application in
+               that order (`linearization_order` emits it for the oracle
+               harness).
   3. return  — results (and, for LL lanes, the linked version) ride the
                inverse `all_to_all` back to the issuing lane, which merges
                them into its persistent per-lane `LinkCtx`.
+
+The state never leaves its shard: `init_dist` builds each shard on its own
+device (from a function of global cell ids, or an array placed shard by
+shard), `apply(..., donate=True)` updates it in place, and `gather` /
+`count_cells` read it back shard by shard.  Route and return run under the
+device scopes `dist.route` and `dist.return`, siblings of the round's
+`engine.*` scopes; `apply` records the host spans `atomics.dist.apply`,
+`.validate` and `.launch`.
 
 `apply_hash` runs the same round for a `HashSpec` CacheHash: ops route by
 key owner (`bucket // nb_local`, the top bits of the bucket hash) and every
@@ -51,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import cachehash as ch
@@ -59,7 +70,17 @@ from repro.core import registry
 from repro.core.deprecation import warn_once
 from repro.core.layout import TableState, WORD_DTYPE
 from repro.core.specs import AtomicSpec, HashSpec
+from repro.kernels import engine_round
 from repro.obs import telemetry as obs_telemetry
+
+# Names in the profiler's trace.  Host spans of `apply`:
+SPAN_APPLY = "atomics.dist.apply"
+SPAN_VALIDATE = "atomics.dist.apply.validate"   # check_kinds, batch width
+SPAN_LAUNCH = "atomics.dist.apply.launch"       # pad, ctx, jit call
+# Device scopes of the sharded round, siblings of the round's `engine.*`
+# scopes (never around them):
+SCOPE_ROUTE = "dist.route"     # owner and rank, packing, outbound all_to_alls
+SCOPE_RETURN = "dist.return"   # inbound all_to_alls, merge into the source
 
 
 @dataclasses.dataclass(frozen=True)
@@ -194,7 +215,13 @@ def _unstack(state):
 
 
 def _restack(state):
-    return jax.tree_util.tree_map(lambda x: x[None], state)
+    """Inside shard_map: the local pytree -> leading [1] shard axis.  The
+    barrier keeps XLA from moving the reshape into the round's branches,
+    where each branch would then hold the table's 1-D fields twice.  On a
+    four-chip mesh XLA then copies the donated `data` whole ahead of the
+    round's branches (ROADMAP A6)."""
+    return jax.tree_util.tree_map(lambda x: x[None],
+                                  lax.optimization_barrier(state))
 
 
 def _mesh_shards(mesh: Mesh, axis: str) -> int:
@@ -225,34 +252,67 @@ def _check_mesh(mesh: Mesh, dspec: DistSpec) -> None:
                          f"says {dspec.n_shards}")
 
 
-def init_dist(mesh: Mesh, dspec: DistSpec, initial: np.ndarray | None = None
-              ) -> DistState:
+def _shard_index(dspec: DistSpec):
+    """Inside shard_map: this device's shard (o = node * devs_per_node +
+    in-node index when hierarchical, as `_pspec` places it)."""
+    if dspec.n_nodes > 1:
+        return (lax.axis_index(dspec.node_axis) * dspec.devs_per_node
+                + lax.axis_index(dspec.axis))
+    return lax.axis_index(dspec.axis)
+
+
+def _local_cells(dspec: DistSpec):
+    """Inside shard_map: the global ids of this shard's cells, in local
+    order (int32[n_local])."""
+    i = _shard_index(dspec).astype(jnp.int32)
+    local = jnp.arange(dspec.n_local, dtype=jnp.int32)
+    if dspec.interleave:
+        return local * dspec.n_shards + i
+    return i * dspec.n_local + local
+
+
+def init_dist(mesh: Mesh, dspec: DistSpec, initial=None) -> DistState:
     """Build the sharded initial state: one local state per shard, stacked
-    and placed `P(axis)` on the mesh (`P((node_axis, axis))` when
-    hierarchical).  `initial` (tables only) is the word[n, k] array of
-    initial GLOBAL logical values."""
-    s = dspec.n_shards
+    on a leading [n_shards] axis placed `P(axis)` on the mesh
+    (`P((node_axis, axis))` when hierarchical).  Each shard is built on its
+    own device by one jitted shard_map, so no device ever holds more than
+    its shard.
+
+    `initial` (tables only) gives the initial GLOBAL logical values: a
+    function of global cell ids (int32[m], traced) -> word[m, k], which
+    each shard evaluates on its own cells, or a word[n, k] array, placed
+    shard by shard.  None starts every cell at zero."""
     _check_mesh(mesh, dspec)
     lsp = dspec.local_spec()
+    spec = _pspec(dspec)
+    args = ()
     if dspec.is_hash:
         if initial is not None:
             raise ValueError("hash tables initialize empty; insert instead")
-        locals_ = [ch.init_hash(lsp) for _ in range(s)]
+
+        def local():
+            return _restack(ch.init_hash(lsp))
+    elif initial is None or callable(initial):
+        def local():
+            words = None if initial is None else initial(
+                _local_cells(dspec))
+            return _restack(engine.init(lsp, words))
     else:
-        if initial is None:
-            shards = [None] * s
-        else:
-            initial = np.asarray(initial)
-            if initial.shape != (dspec.n_global, lsp.k):
-                raise ValueError(f"initial shape {initial.shape} != "
-                                 f"({dspec.n_global}, {lsp.k})")
-            shards = [initial[i::s] if dspec.interleave
-                      else initial[i * dspec.n_local:(i + 1) * dspec.n_local]
-                      for i in range(s)]
-        locals_ = [engine.init(lsp, sh) for sh in shards]
-    local = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *locals_)
-    return DistState(jax.device_put(local,
-                                    NamedSharding(mesh, _pspec(dspec))))
+        initial = np.asarray(initial)
+        if initial.shape != (dspec.n_global, lsp.k):
+            raise ValueError(f"initial shape {initial.shape} != "
+                             f"({dspec.n_global}, {lsp.k})")
+        s, n_local = dspec.n_shards, dspec.n_local
+        stacked = (initial.reshape(n_local, s, lsp.k).swapaxes(0, 1)
+                   if dspec.interleave else
+                   initial.reshape(s, n_local, lsp.k))
+        args = (jax.device_put(stacked, NamedSharding(mesh, spec)),)
+
+        def local(words):
+            return _restack(engine.init(lsp, words[0]))
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=(spec,) * len(args),
+                           out_specs=spec, check_vma=False)
+    return DistState(jax.jit(mapped)(*args))
 
 
 def init_dist_ctx(mesh: Mesh, dspec: DistSpec) -> engine.LinkCtx:
@@ -328,87 +388,104 @@ def _dedup(kind, slot, n: int, p: int):
     return jnp.where(rep != lane, engine.IDLE, kind), rep
 
 
+def _owner_round(lsp: AtomicSpec, mode: str, st, kind, slot, lver, lok,
+                 expected, desired):
+    """The engine's round on the ops a shard received, against a routed
+    per-owner LinkCtx, then the strategy's commit.  Returns (state',
+    ApplyResult, the lanes' linked versions)."""
+    impl = registry.get_strategy(lsp.strategy)
+    m = kind.shape[0]
+    octx = engine.LinkCtx(slot=jnp.where(lok, slot, -1), version=lver,
+                          value=jnp.zeros((m, lsp.k), WORD_DTYPE),
+                          linked=lok)
+    rops = engine.OpBatch(kind, slot, expected, desired)
+    new_data, new_ver, new_octx, res, stats = engine.round_for(
+        lsp, impl, mode)(impl.engine_view(st), st.version, octx, rops)
+    with jax.named_scope(engine.SCOPE_COMMIT):
+        st = impl.commit(st, new_data, new_ver, stats.n_updates, m)
+    return st, res, new_octx.version
+
+
+def _merge_ctx(ctx: engine.LinkCtx, kind, slot, executed, value, ret_ver
+               ) -> engine.LinkCtx:
+    """The source's persistent ctx after its lanes' routed answers: LLs
+    that executed link, SCs that executed consume the link (a dropped SC
+    keeps it)."""
+    is_ll = executed & (kind == engine.LL)
+    is_sc = executed & (kind == engine.SC)
+    return engine.LinkCtx(
+        slot=jnp.where(is_ll, slot, ctx.slot),
+        version=jnp.where(is_ll, ret_ver, ctx.version),
+        value=jnp.where(is_ll[:, None], value, ctx.value),
+        linked=jnp.where(is_ll, True, jnp.where(is_sc, False, ctx.linked)))
+
+
+def _jit_round(mesh: Mesh, local_fn, spec: P, donate: bool):
+    mapped = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec,) * 6,
+                           out_specs=(spec,) * 5, check_vma=False)
+    return jax.jit(mapped, donate_argnums=(0,) if donate else ())
+
+
 @functools.lru_cache(maxsize=256)
-def _build_table_apply(mesh: Mesh, dspec: DistSpec):
+def _build_table_apply(mesh: Mesh, dspec: DistSpec, mode: str,
+                       donate: bool = False):
     s, cap, axis = dspec.n_shards, dspec.cap, dspec.axis
     lsp: AtomicSpec = dspec.local_spec()
     p_local, k = dspec.p_local, lsp.k
 
     def local_fn(state, ctx, kind, slot, expected, desired):
-        st = _unstack(state)
-        impl = registry.get_strategy(lsp.strategy)
+        if ctx is None:
+            ctx = engine.init_ctx(p_local, k)
         lane = jnp.arange(p_local, dtype=jnp.int32)
         active0 = kind != engine.IDLE
-
-        rep = lane
-        if dspec.dedup_loads:
-            kind, rep = _dedup(kind, slot, dspec.n_global, p_local)
-        active = kind != engine.IDLE
-
-        owner, lslot = _owner_and_local(dspec, slot)
-        owner = jnp.where(active, owner, s)
-        rank, fits = _dst_ranks(owner, cap, s, p_local)
+        go = _a2a(axis, s, cap)
 
         # -- route out: ops + the link info the owner needs to arbitrate ----
-        link_ok = ctx.linked & (ctx.slot == slot)     # global-slot compare
-        dst = jnp.where(fits, owner * cap + rank, s * cap)
-        pack = _packer(dst, s * cap)
-        snd_kind = pack(jnp.where(fits, kind, engine.IDLE), engine.IDLE)
-        snd_slot = pack(lslot, 0)
-        snd_exp = pack(expected, 0)
-        snd_des = pack(desired, 0)
-        snd_lver = pack(ctx.version, 0)
-        snd_lok = pack(link_ok, False)
-        go = _a2a(axis, s, cap)
-        r_kind = go(snd_kind).reshape(s * cap)
-        r_slot = go(snd_slot).reshape(s * cap)
-        r_exp = go(snd_exp).reshape(s * cap, k)
-        r_des = go(snd_des).reshape(s * cap, k)
-        r_lver = go(snd_lver).reshape(s * cap)
-        r_lok = go(snd_lok).reshape(s * cap)
+        with jax.named_scope(SCOPE_ROUTE):
+            rep = lane
+            if dspec.dedup_loads:
+                kind, rep = _dedup(kind, slot, dspec.n_global, p_local)
+            active = kind != engine.IDLE
+            owner, lslot = _owner_and_local(dspec, slot)
+            owner = jnp.where(active, owner, s)
+            rank, fits = _dst_ranks(owner, cap, s, p_local)
+            link_ok = ctx.linked & (ctx.slot == slot)  # global-slot compare
+            dst = jnp.where(fits, owner * cap + rank, s * cap)
+            pack = _packer(dst, s * cap)
+            r_kind = go(pack(jnp.where(fits, kind, engine.IDLE),
+                             engine.IDLE)).reshape(s * cap)
+            r_slot = go(pack(lslot, 0)).reshape(s * cap)
+            r_exp = go(pack(expected, 0)).reshape(s * cap, k)
+            r_des = go(pack(desired, 0)).reshape(s * cap, k)
+            r_lver = go(pack(ctx.version, 0)).reshape(s * cap)
+            r_lok = go(pack(link_ok, False)).reshape(s * cap)
 
-        # -- apply: the v2 engine, strategy dispatched through the registry,
-        #    against a routed per-owner LinkCtx ------------------------------
-        octx = engine.LinkCtx(
-            slot=jnp.where(r_lok, r_slot, -1), version=r_lver,
-            value=jnp.zeros((s * cap, k), WORD_DTYPE), linked=r_lok)
-        rops = engine.OpBatch(r_kind, r_slot, r_exp, r_des)
-        new_data, new_ver, new_octx, res, stats = engine.linearize(
-            impl.engine_view(st), st.version, octx, rops)
-        st = impl.commit(st, new_data, new_ver, stats.n_updates, s * cap)
+        # -- apply: the engine's round at the owner --------------------------
+        st, res, lver = _owner_round(lsp, mode, _unstack(state), r_kind,
+                                     r_slot, r_lver, r_lok, r_exp, r_des)
 
         # -- route back: values, success, and the LL-linked version ---------
-        b_val = go(res.value).reshape(s, cap, k)
-        b_suc = go(res.success).reshape(s, cap)
-        b_ver = go(new_octx.version).reshape(s, cap)
-        safe_owner = jnp.clip(owner, 0, s - 1)
-        safe_pos = jnp.maximum(jnp.where(fits, rank, -1), 0)
-        value = jnp.where(fits[:, None], b_val[safe_owner, safe_pos], 0)
-        success = jnp.where(fits, b_suc[safe_owner, safe_pos], False)
-        ret_ver = b_ver[safe_owner, safe_pos]
-        value = value[rep]
-        success = success[rep]
-        overflow = active0 & ~fits[rep]
-
-        # -- merge the routed answers into the persistent source ctx --------
-        is_ll = fits & (kind == engine.LL)
-        is_sc = fits & (kind == engine.SC)     # dropped SCs keep their link
-        nctx = engine.LinkCtx(
-            slot=jnp.where(is_ll, slot, ctx.slot),
-            version=jnp.where(is_ll, ret_ver, ctx.version),
-            value=jnp.where(is_ll[:, None], value, ctx.value),
-            linked=jnp.where(is_ll, True,
-                             jnp.where(is_sc, False, ctx.linked)))
+        with jax.named_scope(SCOPE_RETURN):
+            b_val = go(res.value).reshape(s, cap, k)
+            b_suc = go(res.success).reshape(s, cap)
+            b_ver = go(lver).reshape(s, cap)
+            safe_owner = jnp.clip(owner, 0, s - 1)
+            safe_pos = jnp.maximum(jnp.where(fits, rank, -1), 0)
+            value = jnp.where(fits[:, None], b_val[safe_owner, safe_pos], 0)
+            success = jnp.where(fits, b_suc[safe_owner, safe_pos], False)
+            ret_ver = b_ver[safe_owner, safe_pos]
+            value = value[rep]
+            success = success[rep]
+            overflow = active0 & ~fits[rep]
+            nctx = _merge_ctx(ctx, kind, slot, fits, value, ret_ver)
         return _restack(st), nctx, value, success, overflow
 
-    spec = P(axis)
-    mapped = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec,) * 6,
-                           out_specs=(spec,) * 5, check_vma=False)
-    return jax.jit(mapped)
+    return _jit_round(mesh, local_fn, P(axis), donate)
 
 
 @functools.lru_cache(maxsize=256)
-def _build_table_apply_2level(mesh: Mesh, dspec: DistSpec):
+def _build_table_apply_2level(mesh: Mesh, dspec: DistSpec, mode: str,
+                              donate: bool = False):
     """Hierarchical route -> apply -> return: intra-node combine onto the
     relay device whose in-node index matches the owner's, then ONE
     cross-node all_to_all (DESIGN.md §9).
@@ -431,97 +508,84 @@ def _build_table_apply_2level(mesh: Mesh, dspec: DistSpec):
     p_local, k = dspec.p_local, lsp.k
 
     def local_fn(state, ctx, kind, slot, expected, desired):
-        st = _unstack(state)
-        impl = registry.get_strategy(lsp.strategy)
+        if ctx is None:
+            ctx = engine.init_ctx(p_local, k)
         active0 = kind != engine.IDLE
-
-        rep = jnp.arange(p_local, dtype=jnp.int32)
-        if dspec.dedup_loads:
-            kind, rep = _dedup(kind, slot, dspec.n_global, p_local)
-        active = kind != engine.IDLE
-
-        owner, lslot = _owner_and_local(dspec, slot)
-        o_node = jnp.where(active, owner // d, nn)
-        o_dev = jnp.where(active, owner % d, d)
-
-        # -- phase 1 out: intra-node combine onto the o_dev relay -----------
-        link_ok = ctx.linked & (ctx.slot == slot)
-        rank1, fits1 = _dst_ranks(o_dev, cap1, d, p_local)
-        dst1 = jnp.where(fits1, o_dev * cap1 + rank1, d * cap1)
-        pack1 = _packer(dst1, d * cap1)
         go1 = _a2a(axis, d, cap1)
-        r1_kind = go1(pack1(jnp.where(fits1, kind, engine.IDLE),
-                            engine.IDLE)).reshape(d * cap1)
-        r1_slot = go1(pack1(lslot, 0)).reshape(d * cap1)
-        r1_node = go1(pack1(o_node, nn)).reshape(d * cap1)
-        r1_exp = go1(pack1(expected, 0)).reshape(d * cap1, k)
-        r1_des = go1(pack1(desired, 0)).reshape(d * cap1, k)
-        r1_lver = go1(pack1(ctx.version, 0)).reshape(d * cap1)
-        r1_lok = go1(pack1(link_ok, False)).reshape(d * cap1)
-
-        # -- phase 2 out: ONE cross-node hop to the owner node --------------
-        key2 = jnp.where(r1_kind != engine.IDLE, r1_node, nn)
-        rank2, fits2 = _dst_ranks(key2, cap2, nn, d * cap1)
-        dst2 = jnp.where(fits2, key2 * cap2 + rank2, nn * cap2)
-        pack2 = _packer(dst2, nn * cap2)
         go2 = _a2a(node_axis, nn, cap2)
-        r2_kind = go2(pack2(jnp.where(fits2, r1_kind, engine.IDLE),
-                            engine.IDLE)).reshape(nn * cap2)
-        r2_slot = go2(pack2(r1_slot, 0)).reshape(nn * cap2)
-        r2_exp = go2(pack2(r1_exp, 0)).reshape(nn * cap2, k)
-        r2_des = go2(pack2(r1_des, 0)).reshape(nn * cap2, k)
-        r2_lver = go2(pack2(r1_lver, 0)).reshape(nn * cap2)
-        r2_lok = go2(pack2(r1_lok, False)).reshape(nn * cap2)
 
-        # -- apply at the owner (same engine round as the flat path) --------
-        octx = engine.LinkCtx(
-            slot=jnp.where(r2_lok, r2_slot, -1), version=r2_lver,
-            value=jnp.zeros((nn * cap2, k), WORD_DTYPE), linked=r2_lok)
-        rops = engine.OpBatch(r2_kind, r2_slot, r2_exp, r2_des)
-        new_data, new_ver, new_octx, res, stats = engine.linearize(
-            impl.engine_view(st), st.version, octx, rops)
-        st = impl.commit(st, new_data, new_ver, stats.n_updates, nn * cap2)
+        with jax.named_scope(SCOPE_ROUTE):
+            rep = jnp.arange(p_local, dtype=jnp.int32)
+            if dspec.dedup_loads:
+                kind, rep = _dedup(kind, slot, dspec.n_global, p_local)
+            active = kind != engine.IDLE
+            owner, lslot = _owner_and_local(dspec, slot)
+            o_node = jnp.where(active, owner // d, nn)
+            o_dev = jnp.where(active, owner % d, d)
 
-        # -- return hop 2: owner node -> relay ------------------------------
-        b2_val = go2(res.value).reshape(nn, cap2, k)
-        b2_suc = go2(res.success).reshape(nn, cap2)
-        b2_ver = go2(new_octx.version).reshape(nn, cap2)
-        safe_n = jnp.clip(key2, 0, nn - 1)
-        safe_r2 = jnp.maximum(jnp.where(fits2, rank2, -1), 0)
-        v1 = jnp.where(fits2[:, None], b2_val[safe_n, safe_r2], 0)
-        s1 = jnp.where(fits2, b2_suc[safe_n, safe_r2], False)
-        ver1 = b2_ver[safe_n, safe_r2]
+            # -- phase 1 out: intra-node combine onto the o_dev relay -------
+            link_ok = ctx.linked & (ctx.slot == slot)
+            rank1, fits1 = _dst_ranks(o_dev, cap1, d, p_local)
+            dst1 = jnp.where(fits1, o_dev * cap1 + rank1, d * cap1)
+            pack1 = _packer(dst1, d * cap1)
+            r1_kind = go1(pack1(jnp.where(fits1, kind, engine.IDLE),
+                                engine.IDLE)).reshape(d * cap1)
+            r1_slot = go1(pack1(lslot, 0)).reshape(d * cap1)
+            r1_node = go1(pack1(o_node, nn)).reshape(d * cap1)
+            r1_exp = go1(pack1(expected, 0)).reshape(d * cap1, k)
+            r1_des = go1(pack1(desired, 0)).reshape(d * cap1, k)
+            r1_lver = go1(pack1(ctx.version, 0)).reshape(d * cap1)
+            r1_lok = go1(pack1(link_ok, False)).reshape(d * cap1)
 
-        # -- return hop 1: relay -> source (the fits2 bit rides back so the
-        #    source learns which lanes ACTUALLY executed) --------------------
-        b1_val = go1(v1).reshape(d, cap1, k)
-        b1_suc = go1(s1).reshape(d, cap1)
-        b1_ver = go1(ver1).reshape(d, cap1)
-        b1_exe = go1(fits2).reshape(d, cap1)
-        safe_dev = jnp.clip(o_dev, 0, d - 1)
-        safe_r1 = jnp.maximum(jnp.where(fits1, rank1, -1), 0)
-        executed = fits1 & b1_exe[safe_dev, safe_r1]
-        value = jnp.where(executed[:, None], b1_val[safe_dev, safe_r1], 0)
-        success = jnp.where(executed, b1_suc[safe_dev, safe_r1], False)
-        ret_ver = b1_ver[safe_dev, safe_r1]
-        value = value[rep]
-        success = success[rep]
-        overflow = active0 & ~executed[rep]
+            # -- phase 2 out: ONE cross-node hop to the owner node ----------
+            key2 = jnp.where(r1_kind != engine.IDLE, r1_node, nn)
+            rank2, fits2 = _dst_ranks(key2, cap2, nn, d * cap1)
+            dst2 = jnp.where(fits2, key2 * cap2 + rank2, nn * cap2)
+            pack2 = _packer(dst2, nn * cap2)
+            r2_kind = go2(pack2(jnp.where(fits2, r1_kind, engine.IDLE),
+                                engine.IDLE)).reshape(nn * cap2)
+            r2_slot = go2(pack2(r1_slot, 0)).reshape(nn * cap2)
+            r2_exp = go2(pack2(r1_exp, 0)).reshape(nn * cap2, k)
+            r2_des = go2(pack2(r1_des, 0)).reshape(nn * cap2, k)
+            r2_lver = go2(pack2(r1_lver, 0)).reshape(nn * cap2)
+            r2_lok = go2(pack2(r1_lok, False)).reshape(nn * cap2)
 
-        is_ll = executed & (kind == engine.LL)
-        is_sc = executed & (kind == engine.SC)   # dropped SCs keep their link
-        nctx = engine.LinkCtx(
-            slot=jnp.where(is_ll, slot, ctx.slot),
-            version=jnp.where(is_ll, ret_ver, ctx.version),
-            value=jnp.where(is_ll[:, None], value, ctx.value),
-            linked=jnp.where(is_ll, True,
-                             jnp.where(is_sc, False, ctx.linked)))
+        # -- apply at the owner (the same round as the flat path) -----------
+        st, res, lver = _owner_round(lsp, mode, _unstack(state), r2_kind,
+                                     r2_slot, r2_lver, r2_lok, r2_exp,
+                                     r2_des)
+
+        with jax.named_scope(SCOPE_RETURN):
+            # -- return hop 2: owner node -> relay ---------------------------
+            b2_val = go2(res.value).reshape(nn, cap2, k)
+            b2_suc = go2(res.success).reshape(nn, cap2)
+            b2_ver = go2(lver).reshape(nn, cap2)
+            safe_n = jnp.clip(key2, 0, nn - 1)
+            safe_r2 = jnp.maximum(jnp.where(fits2, rank2, -1), 0)
+            v1 = jnp.where(fits2[:, None], b2_val[safe_n, safe_r2], 0)
+            s1 = jnp.where(fits2, b2_suc[safe_n, safe_r2], False)
+            ver1 = b2_ver[safe_n, safe_r2]
+
+            # -- return hop 1: relay -> source (the fits2 bit rides back so
+            #    the source learns which lanes ACTUALLY executed) -----------
+            b1_val = go1(v1).reshape(d, cap1, k)
+            b1_suc = go1(s1).reshape(d, cap1)
+            b1_ver = go1(ver1).reshape(d, cap1)
+            b1_exe = go1(fits2).reshape(d, cap1)
+            safe_dev = jnp.clip(o_dev, 0, d - 1)
+            safe_r1 = jnp.maximum(jnp.where(fits1, rank1, -1), 0)
+            executed = fits1 & b1_exe[safe_dev, safe_r1]
+            value = jnp.where(executed[:, None], b1_val[safe_dev, safe_r1],
+                              0)
+            success = jnp.where(executed, b1_suc[safe_dev, safe_r1], False)
+            ret_ver = b1_ver[safe_dev, safe_r1]
+            value = value[rep]
+            success = success[rep]
+            overflow = active0 & ~executed[rep]
+            nctx = _merge_ctx(ctx, kind, slot, executed, value, ret_ver)
         return _restack(st), nctx, value, success, overflow
 
-    spec = P((node_axis, axis))
-    mapped = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec,) * 6,
-                           out_specs=(spec,) * 5, check_vma=False)
-    return jax.jit(mapped)
+    return _jit_round(mesh, local_fn, P((node_axis, axis)), donate)
 
 
 def _pad_ops(ops: engine.OpBatch, p: int) -> engine.OpBatch:
@@ -556,41 +620,52 @@ def _check_width(q: int, dspec: DistSpec) -> None:
 
 
 def apply(mesh: Mesh, dspec: DistSpec, dstate: DistState, ops: engine.OpBatch,
-          ctx: engine.LinkCtx | None = None):
+          ctx: engine.LinkCtx | None = None, *, donate: bool = False):
     """Linearize a mixed table batch across the mesh in ONE collective round.
 
     `ops` has up to p_global lanes laid out source-major (lane i issues from
     shard i // p_local; missing trailing lanes are IDLE-padded and their
     results trimmed away); `ctx` carries per-lane LL/SC links across
-    batches.
+    batches.  `donate=True` hands the state's buffers to the round, which
+    updates each shard in place instead of holding the old and the new
+    state; the passed `dstate` must not be used afterwards.  Donation is
+    skipped on CPU backends, which cannot donate.
 
     Returns (dstate', ctx', ApplyResult, overflow) where `overflow` is the
     per-lane bool mask of ops rejected by route capacity — reported, never
     silently dropped; rejected lanes have success=False and no table effect.
     """
-    if dspec.is_hash:
-        raise TypeError("hash DistSpec: use distributed.apply_hash")
-    engine.check_kinds(ops.kind, engine.TABLE_KINDS, "table")
-    q, k = ops.kind.shape[0], dspec.inner.k
-    _check_width(q, dspec)
-    ops = _pad_ops(ops, dspec.p_global)
-    ctx = engine.init_ctx(dspec.p_global, k) if ctx is None \
-        else _pad_ctx(ctx, dspec.p_global, k)
-    fn = (_build_table_apply_2level(mesh, dspec) if dspec.n_nodes > 1
-          else _build_table_apply(mesh, dspec))
-    local, nctx, value, success, overflow = fn(
-        dstate.local, ctx, ops.kind, ops.slot, ops.expected, ops.desired)
-    if q != dspec.p_global:
-        nctx = engine.LinkCtx(*[x[:q] for x in nctx])
-        value, success, overflow = value[:q], success[:q], overflow[:q]
-    if obs_telemetry.carry_in(dstate.local, ops.kind) is not None:
-        # One tiny scalar-accumulate dispatch per collective round when
-        # counters are on (threading the pytree through shard_map is not
-        # worth the churn); zero work when off.  `collective_words(dspec)`
-        # is static per dspec, so the jitted accumulator never retraces.
-        obs_telemetry.record_dist(overflow, collective_words(dspec))
-    return (DistState(local), nctx, engine.ApplyResult(value, success),
-            overflow)
+    with TraceAnnotation(SPAN_APPLY):
+        with TraceAnnotation(SPAN_VALIDATE):
+            if dspec.is_hash:
+                raise TypeError("hash DistSpec: use distributed.apply_hash")
+            engine.check_kinds(ops.kind, engine.TABLE_KINDS, "table")
+            q, k = ops.kind.shape[0], dspec.inner.k
+            _check_width(q, dspec)
+        telem = obs_telemetry.carry_in(dstate.local, ops.kind)
+        with TraceAnnotation(SPAN_LAUNCH):
+            ops = _pad_ops(ops, dspec.p_global)
+            if ctx is not None:
+                ctx = _pad_ctx(ctx, dspec.p_global, k)
+            build = (_build_table_apply_2level if dspec.n_nodes > 1
+                     else _build_table_apply)
+            fn = build(mesh, dspec, engine_round.configured_mode(),
+                       donate and jax.default_backend() != "cpu")
+            local, nctx, value, success, overflow = fn(
+                dstate.local, ctx, ops.kind, ops.slot, ops.expected,
+                ops.desired)
+        if q != dspec.p_global:
+            nctx = engine.LinkCtx(*[x[:q] for x in nctx])
+            value, success, overflow = value[:q], success[:q], overflow[:q]
+        if telem is not None:
+            # One tiny scalar-accumulate dispatch per collective round when
+            # counters are on (threading the pytree through shard_map is
+            # not worth the churn); zero work when off.
+            # `collective_words(dspec)` is static per dspec, so the jitted
+            # accumulator never retraces.
+            obs_telemetry.record_dist(overflow, collective_words(dspec))
+        return (DistState(local), nctx, engine.ApplyResult(value, success),
+                overflow)
 
 
 class DistRoundHandle:
@@ -927,11 +1002,96 @@ def apply_hash(mesh: Mesh, dspec: DistSpec, dstate: DistState,
 
 
 # ---------------------------------------------------------------------------
-# Host-side inspection (tests / debugging).
+# Read-back: shard by shard for tables of any size; whole for small ones.
 # ---------------------------------------------------------------------------
 
+_CELL_FIELDS = ("data", "version", "bptr", "mark", "lock")
+
+
+def _rows(st, take):
+    """A local table state whose cells are `take` of st's (applied to each
+    per-cell field; node pool, ring and counters kept whole), so the
+    strategy's `logical` gives those cells' values."""
+    return st._replace(**{f: take(getattr(st, f)) for f in _CELL_FIELDS
+                          if getattr(st, f).shape[0]})
+
+
+def _check_table(dspec: DistSpec) -> None:
+    if dspec.is_hash:
+        raise TypeError("hash DistSpec: tables only")
+
+
+@functools.lru_cache(maxsize=64)
+def _build_gather(mesh: Mesh, dspec: DistSpec):
+    impl = registry.get_strategy(dspec.inner.strategy)
+    axes = (dspec.node_axis, dspec.axis) if dspec.n_nodes > 1 \
+        else dspec.axis
+
+    def local_fn(state, slots):
+        owner, lslot = _owner_and_local(dspec, slots)
+        mine = owner == _shard_index(dspec)
+        sub = _rows(_unstack(state),
+                    lambda x: x[jnp.where(mine, lslot, 0)])
+        vals = jnp.where(mine[:, None], impl.logical(sub), 0)
+        vers = jnp.where(mine, sub.version, 0)
+        return lax.psum(vals, axes), lax.psum(vers, axes)
+
+    mapped = jax.shard_map(local_fn, mesh=mesh, in_specs=(_pspec(dspec), P()),
+                           out_specs=(P(), P()), check_vma=False)
+    return jax.jit(mapped)
+
+
+def gather(mesh: Mesh, dspec: DistSpec, dstate: DistState, slots):
+    """Logical values word[q, k] and versions uint32[q] of the global
+    `slots` (tables): each shard reads the slots it owns from its own
+    cells, and the answers are assembled per lane on every device.  No
+    device holds more than its shard and q answers."""
+    _check_table(dspec)
+    slots = jnp.asarray(slots, jnp.int32)
+    return _build_gather(mesh, dspec)(dstate.local, slots)
+
+
+def count_cells(mesh: Mesh, dspec: DistSpec, dstate: DistState, pred,
+                *args, block: int = 1 << 22) -> int:
+    """The number of cells of a table for which `pred(cells, values,
+    versions, *args)` holds: cells int32[m] global ids, values word[m, k]
+    logical values, versions uint32[m] -> bool[m].  Each shard folds it
+    over its own cells in blocks of `block` (m = min(block, n_local)),
+    so no device holds more than its shard and one block's temporaries;
+    `args` ride along replicated."""
+    _check_table(dspec)
+    impl = registry.get_strategy(dspec.inner.strategy)
+    m = min(block, dspec.n_local)
+    if dspec.n_local % m:
+        raise ValueError(f"block {m} does not divide n_local "
+                         f"{dspec.n_local}")
+
+    def local_fn(state, *args):
+        st = _unstack(state)
+        cells = _local_cells(dspec)
+
+        def body(j, total):
+            def take(x):
+                return lax.dynamic_slice_in_dim(x, j * m, m)
+            sub = _rows(st, take)
+            hit = pred(take(cells), impl.logical(sub), sub.version, *args)
+            return total + jnp.sum(hit.astype(jnp.int32))
+
+        total = lax.fori_loop(0, dspec.n_local // m, body, jnp.int32(0))
+        return total[None]
+
+    spec = _pspec(dspec)
+    mapped = jax.shard_map(local_fn, mesh=mesh,
+                           in_specs=(spec,) + (P(),) * len(args),
+                           out_specs=spec, check_vma=False)
+    per_shard = jax.jit(mapped)(dstate.local, *args)
+    return int(np.sum(np.asarray(per_shard, np.int64)))
+
+
 def logical(dspec: DistSpec, dstate: DistState) -> jax.Array:
-    """Global logical values [n, k], de-sharded (tables only)."""
+    """Global logical values [n, k], de-sharded (tables only), on one
+    device: for small tables.  A large one reads back through `gather`
+    and `count_cells`."""
     impl = registry.get_strategy(dspec.inner.strategy)
     vals = jax.vmap(impl.logical)(dstate.local)      # [s, n_local, k]
     if dspec.interleave:
@@ -940,7 +1100,8 @@ def logical(dspec: DistSpec, dstate: DistState) -> jax.Array:
 
 
 def versions(dspec: DistSpec, dstate: DistState) -> jax.Array:
-    """Global cell versions [n] (tables only)."""
+    """Global cell versions [n] (tables only), de-sharded on one device:
+    for small tables, as `logical`."""
     ver = dstate.local.version                       # [s, n_local]
     if dspec.interleave:
         return jnp.swapaxes(ver, 0, 1).reshape(-1)
@@ -1144,7 +1305,7 @@ def make_apply(mesh: Mesh, axis: str, n: int, k: int, p_local: int,
     dspec = DistSpec(AtomicSpec(n, k, "plain"), axis, s, p_local,
                      route_capacity=route_capacity, dedup_loads=dedup_loads,
                      interleave=interleave)
-    fn = _build_table_apply(mesh, dspec)
+    fn = _build_table_apply(mesh, dspec, engine_round.configured_mode())
 
     @jax.jit
     def apply_ops(table: ShardedTable, ops: engine.OpBatch):

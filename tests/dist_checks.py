@@ -17,7 +17,8 @@ import zlib
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-for path in (_HERE, os.path.join(_HERE, "..", "src")):
+for path in (_HERE, os.path.join(_HERE, "..", "src"),
+             os.path.join(_HERE, "..")):
     if path not in sys.path:
         sys.path.insert(0, path)
 
@@ -607,6 +608,111 @@ def scenario_elastic(strategy: str):
     assert len(lead.sharding.device_set) in (2, 4, 8)
 
 
+def _traced_names(fn, log_dir: str):
+    """(scopes, spans) the benchmark's trace reduction finds in a traced
+    call of `fn`, inside the harness's own window spans."""
+    from bench import devtrace
+    devtrace.start(log_dir)
+    with jax.profiler.TraceAnnotation(devtrace.SPAN_DISPATCH):
+        out = fn()
+    with jax.profiler.TraceAnnotation(devtrace.SPAN_FETCH):
+        jax.block_until_ready(out)
+    devtrace.stop()
+    summary = devtrace.reduce(devtrace.load(log_dir), len(jax.devices()))
+    return set(summary.scope_ns), set(summary.span_self_ns)
+
+
+def scenario_round(mode: str):
+    """The sharded round on the engine's kernels, in one engine-kernel mode
+    (`pallas`, interpreted here, or `xla`): on a stream like the
+    benchmark's YCSB-A (50/50 LOAD/STORE, Zipf 0.99 ranks, so a hot record
+    on one shard) every shard runs `engine.round_for` and the results equal
+    the shared oracle's and the benchmark's reference (`TableRef`).  The
+    table is built shard by shard from a function of global cell ids,
+    equal to the build from the same array; read back shard by shard,
+    `gather` equals `logical` / `versions` at the touched records and the
+    blocked untouched count equals the whole-table count.  A traced call
+    holds the `dist.*` scopes and the three `atomics.dist.apply` spans."""
+    import tempfile
+
+    import jax.numpy as jnp
+    from bench import gen, reference
+    from bench.systems import dist_table
+
+    os.environ["BIGATOMIC_ENGINE_KERNEL"] = mode
+    n, k, shards, pl, seed = 4096, 8, 4, 16, 2 ** 33 + 17
+    mesh = _mesh(shards)
+    dspec = dsb.DistSpec(atomics.AtomicSpec(n, k, "cached_me",
+                                            p_max=shards * pl),
+                         "shard", shards, pl)
+    start = gen.cell_words(seed, np.arange(n), k)
+    st = dsb.init_dist(mesh, dspec,
+                       lambda cells: gen.cell_words(seed, cells, k, jnp))
+    for got, want in zip(jax.tree.leaves(st),
+                         jax.tree.leaves(dsb.init_dist(mesh, dspec, start))):
+        assert got.sharding.is_equivalent_to(want.sharding, got.ndim)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    traffic = {"mix": {"LOAD": 0.5, "STORE": 0.5}, "keys": "zipf",
+               "theta": 0.99, "pool_batches": 4}
+    pool = gen.draw(traffic, space=n, lanes=dspec.p_global, width=k,
+                    seed=seed, codes=dist_table.CODES)
+    slots = pool.index.astype(np.int32)
+    hot = np.bincount(slots.ravel(), minlength=n).argmax()
+    assert np.sum(slots == hot) >= 2 * len(pool), "no hot record"
+    ops = [atomics.make_ops(pool.kind[b], slots[b], None, pool.value[b], k=k)
+           for b in range(len(pool))]
+    jaxpr = str(jax.make_jaxpr(dsb._build_table_apply(mesh, dspec, mode))(
+        st.local, None, *ops[0]))
+    assert ("pallas_call" in jaxpr) == (mode == "pallas"), mode
+
+    oracle = TableOracle(n, k, dspec.p_global, initial=start)
+    ref = reference.TableRef(seed, dict(enumerate(slots)), k,
+                             dist_table.CODES)
+    for b, batch in enumerate(ops):
+        order, ovf_ref = dsb.linearization_order(dspec, batch)
+        assert not ovf_ref.any()
+        st, _, res, ovf = dsb.apply(mesh, dspec, st, batch, donate=True)
+        assert not np.asarray(ovf).any()
+        oracle.step_and_check(batch, result=res,
+                              logical=dsb.logical(dspec, st),
+                              version=dsb.versions(dspec, st), order=order,
+                              msg=f"round {mode} batch {b}")
+        want_v, want_s = ref.apply(b, pool.kind[b], pool.value[b])
+        np.testing.assert_array_equal(np.asarray(res.value), want_v)
+        np.testing.assert_array_equal(np.asarray(res.success), want_s)
+
+    touched = np.unique(slots)
+    vals, vers = dsb.gather(mesh, dspec, st, touched)
+    np.testing.assert_array_equal(np.asarray(vals), oracle.data[touched])
+    np.testing.assert_array_equal(np.asarray(vers), oracle.version[touched])
+    want_v, want_ver = ref.read(touched)
+    np.testing.assert_array_equal(np.asarray(vals), want_v)
+    np.testing.assert_array_equal(np.asarray(vers), want_ver)
+
+    spec = dist_table.Spec(mesh, dspec)
+    victim = next(i for i in range(n) if i not in set(touched.tolist()))
+    for state, planted in ((st, 0),
+                           (dist_table.change_record(st, victim, "data"), 1)):
+        moved = (np.any(np.asarray(dsb.logical(dspec, state)) != start,
+                        axis=1) | (np.asarray(dsb.versions(dspec, state))
+                                   != 0))
+        assert moved[touched].any()
+        moved[touched] = False
+        assert int(moved.sum()) == planted
+        for block in (64, 1 << 22):
+            assert dist_table.untouched_mismatches(
+                spec, seed, state, touched, block=block) == planted, block
+
+    if mode == "xla":
+        with tempfile.TemporaryDirectory() as log_dir:
+            scopes, spans = _traced_names(
+                lambda: dsb.apply(mesh, dspec, st, ops[0]), log_dir)
+        assert {dsb.SCOPE_ROUTE, dsb.SCOPE_RETURN} <= scopes, scopes
+        assert {dsb.SPAN_APPLY, dsb.SPAN_VALIDATE,
+                dsb.SPAN_LAUNCH} <= spans, spans
+
+
 SCENARIOS = {
     "mixed": scenario_mixed,
     "levers": scenario_levers,
@@ -621,6 +727,7 @@ SCENARIOS = {
     "twolevel": scenario_twolevel,
     "executor": scenario_executor,
     "elastic": scenario_elastic,
+    "round": scenario_round,
 }
 
 

@@ -114,6 +114,16 @@ def test_oversubscribed_executor_recovers_from_shard_loss(strategy):
     _run("executor", strategy)
 
 
+@pytest.mark.parametrize("mode", ["pallas", "xla"])
+def test_sharded_round_runs_the_engines_kernels(mode):
+    """Each shard runs `engine.round_for` (Pallas kernels interpreted, or
+    the XLA tiers) on a YCSB-A-like Zipf stream: equal to the oracle and
+    the benchmark's reference; the per-device build, the sharded gather
+    and the blocked untouched count agree with their whole-table twins;
+    a traced call holds the `dist.*` scopes and spans."""
+    _run("round", mode)
+
+
 def test_elastic_reshard_round_trips():
     """Table 8->6->4->8 preserving values+versions (LL link survives);
     training state through the same chain bit-identically, with dropped

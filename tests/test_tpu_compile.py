@@ -1,12 +1,12 @@
 """The main path's kernels compile for a TPU v5e at a deployment's size.
 
 Nothing here runs: each case compiles for a described (not attached)
-`v5e:2x2` topology, so Mosaic, the TPU kernel compiler, refuses here
-what it would refuse on the chip.  Each case checks that the compiled
-program holds the Pallas kernel (`tpu_custom_call`) and that it needs no
-more temporary device memory than the bytes of the table state it
-updates: the kernels take the table in its own layout, never a copy
-padded to the chip's (8, 128) tiles.
+`v5e:2x2` topology, one chip of it or all four, so Mosaic, the TPU
+kernel compiler, refuses here what it would refuse on the chip.  Each
+case checks that the compiled program holds the Pallas kernel
+(`tpu_custom_call`) and that it needs no more temporary device memory
+than the bytes of the table state it updates: the kernels take the table
+in its own layout, never a copy padded to the chip's (8, 128) tiles.
 """
 
 import os
@@ -14,7 +14,7 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro import atomics
 from repro.core import engine
@@ -26,7 +26,7 @@ P = 8192             # lanes per batch
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -40,9 +40,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _on(sharding, tree):
@@ -104,3 +109,40 @@ def test_scrub_digest_compiles(one_chip):
     state = _on(one_chip, jax.eval_shape(lambda: atomics.init(spec)))
     compiled = _cell_digest.lower(spec, state, "pallas", False).compile()
     _check(compiled, _nbytes(state))
+
+
+def test_dist_apply_compiles(topo, one_chip, monkeypatch):
+    """One donating `atomics.dist.apply` round of a cached_me table sharded
+    over the four chips, n = 2^24 a chip: every shard holds both tiers'
+    kernels, the exchange is all_to_alls, the round updates each shard in
+    place (its state aliased to the output), and it needs no more
+    temporary memory a chip than one chip's donating round of the same
+    table."""
+    from repro.core import distributed
+    from repro.launch.mesh import make_mesh
+    monkeypatch.setattr(engine_round, "resolved_mode",
+                        lambda mode=None: ("pallas", False))
+    mesh = make_mesh((4,), ("shard",), devices=topo.devices)
+    dspec = atomics.DistSpec(atomics.AtomicSpec(4 * N, 8, "cached_me",
+                                                p_max=P), "shard", 4, P // 4)
+    sharded = NamedSharding(mesh, PartitionSpec("shard"))
+    state = _on(sharded, jax.eval_shape(lambda: jax.tree.map(
+        lambda x: jnp.stack([x] * 4), atomics.init(dspec.local_spec()))))
+    ops = _on(sharded, jax.eval_shape(lambda: atomics.make_ops(
+        jnp.zeros(P, jnp.int32), jnp.zeros(P, jnp.int32), k=8)))
+    compiled = distributed._build_table_apply(
+        mesh, dspec, "pallas", True).lower(state, None, *ops).compile()
+    shard_bytes = _nbytes(state) // 4
+    _check(compiled, shard_bytes, kernels=2)
+    assert "all-to-all" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= shard_bytes
+
+    lsp = dspec.local_spec()
+    one = _on(one_chip, jax.eval_shape(lambda: atomics.init(lsp)))
+    one_ops = _on(one_chip, jax.eval_shape(lambda: atomics.make_ops(
+        jnp.zeros(P, jnp.int32), jnp.zeros(P, jnp.int32), k=8)))
+    alone = engine._apply_donated.lower(lsp, one, one_ops, None, "pallas",
+                                        None).compile()
+    assert memory.temp_size_in_bytes <= \
+        alone.memory_analysis().temp_size_in_bytes
